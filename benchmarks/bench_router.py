@@ -39,6 +39,7 @@ import os
 import time
 import zlib
 
+import jax
 import numpy as np
 
 from benchmarks.common import p99_ms
@@ -258,7 +259,9 @@ def phase_typed_errors(front, addrs, imgs, smoke: bool) -> dict:
     # ServiceClosed over the wire, and nothing sees a dropped connection
     cfgd = dict(worker_config(smoke))
     cfgd["faults"] = {"latency_ms": 150.0}
-    proc, addr = spawn_worker(cfgd, worker_id=9)
+    cfgd.pop("interpret")  # resolved for the CPU backend below
+    # drain semantics need no chip, and every chip already has its worker
+    proc, addr = spawn_worker(cfgd, worker_id=9, env={"JAX_PLATFORMS": "cpu"})
     closed_hits = late_results = 0
     try:
         with Connection(addr) as conn:
@@ -337,6 +340,9 @@ def phase_worker_kill(front, procs, n_workers: int, imgs) -> dict:
 
 # -------------------------------------------------------------------- driver
 def run(smoke: bool = False) -> dict:
+    # the workers hold the chips, one each (spawn_worker pins them); this
+    # process computes every reference on the CPU and never takes one
+    jax.config.update("jax_platforms", "cpu")
     n_workers = 2 if smoke else 4
     n_bitexact = 4 if smoke else 12
     n_calib = 40 if smoke else 120

@@ -112,7 +112,7 @@ def fit_collective(shards, width, reps) -> dict:
     import jax
     import jax.numpy as jnp
     from jax import lax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.dispatch import DispatchPolicy
@@ -145,7 +145,7 @@ def fit_collective(shards, width, reps) -> dict:
         for name, f in (("ppermute", pp), ("all_to_all", a2a)):
             fn = jax.jit(shard_map(
                 f, mesh=mesh, in_specs=P("rows", None),
-                out_specs=P("rows", None), check_rep=False,
+                out_specs=P("rows", None), check_vma=False,
             ))
             t = _time(fn, x, reps=reps)
             points[name].append((float(elems), t * 1e6))

@@ -13,8 +13,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.morphology import CONFIG as MORPH
 from repro.obs import DEFAULT_LATENCY_BUCKETS_MS, Histogram, quantile_from_snapshot
+
+# every benchmark imports this module: share one persistent compile cache
+enable_compile_cache()
 
 
 def time_fn(fn, *args, warmup: int = 2, iters: int = 10) -> float:

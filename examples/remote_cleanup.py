@@ -14,14 +14,21 @@ Every remote result is compared bit-for-bit against a direct in-process
 boundary. The fleet-wide ``stats()`` at the end is merged from each
 worker's metrics registry over the same protocol.
 
+On a TPU host each worker holds one chip (``spawn_worker`` pins worker
+``i`` to chip ``i``), so this process computes its reference on the CPU
+and never takes a chip itself.
+
     PYTHONPATH=src python examples/remote_cleanup.py
 """
 import time
 
+import jax
 import numpy as np
 
 from repro.serve.ingress import Frontier, IngressClient, spawn_worker
 from repro.serve.morph import MorphService, ServiceConfig
+
+jax.config.update("jax_platforms", "cpu")  # the chips belong to the workers
 
 BUCKET = (128, 128)
 rng = np.random.default_rng(0)

@@ -56,19 +56,26 @@ def resolve_interpret(
 
     Precedence: explicit argument > ``DispatchPolicy.interpret`` >
     ``REPRO_PALLAS_INTERPRET`` env var > backend default (compiled Mosaic on
-    TPU, interpret elsewhere). Kernel entry points (kernels/ops.py) call this
-    once instead of hard-coding ``interpret=True``, so production serving on
-    TPU never silently runs interpreted Pallas; tests keep pinning
-    ``interpret=True`` explicitly.
+    TPU, interpret elsewhere). Every kernel entry point defaults to
+    ``interpret=None`` and calls this, so nothing interprets on a TPU by
+    default; asking for interpret mode on a TPU is an error, not a slow
+    fallback that would hide the device.
     """
-    if interpret is not None:
-        return bool(interpret)
-    if policy is not None and policy.interpret is not None:
-        return policy.interpret
-    env = os.environ.get(INTERPRET_ENV)
-    if env is not None:
-        return env.strip().lower() not in ("0", "false", "no", "off", "")
-    return jax.default_backend() != "tpu"
+    if interpret is None and policy is not None:
+        interpret = policy.interpret
+    if interpret is None:
+        env = os.environ.get(INTERPRET_ENV)
+        if env is not None:
+            interpret = env.strip().lower() not in ("0", "false", "no", "off", "")
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None:
+        return not on_tpu
+    if interpret and on_tpu:
+        raise ValueError(
+            "interpreted Pallas requested on a TPU backend (argument, policy "
+            f"or {INTERPRET_ENV}); the kernels compile there"
+        )
+    return bool(interpret)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -81,6 +81,20 @@ def widen_dtype(dtype) -> jnp.dtype:
     return dtype
 
 
+def vmem_dtype(dtype) -> jnp.dtype:
+    """Dtype the Pallas kernels run min/max in once a block is in VMEM.
+
+    Mosaic has no 8- or 16-bit integer min/max on TPU (``arith.minui`` /
+    ``arith.minsi`` fail to legalize), so narrow integers widen to int32 in
+    VMEM and are stored back in their own dtype. The widening is
+    order-preserving, so results stay bit-exact; HBM traffic is unchanged.
+    """
+    dtype = jnp.dtype(dtype)
+    if jnp.issubdtype(dtype, jnp.integer) and dtype.itemsize < 4:
+        return jnp.dtype(jnp.int32)
+    return dtype
+
+
 def widened_sub(a: Array, b: Array) -> Array:
     """``a - b`` computed (and returned) in ``widen_dtype`` of the inputs."""
     wide = widen_dtype(jnp.result_type(a, b))

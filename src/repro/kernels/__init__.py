@@ -2,8 +2,9 @@
 
 Each kernel file pairs with a pure-jnp oracle in ref.py; ops.py exposes the
 jit'd hybrid dispatch API. The 2-D operators default to the fused
-single-``pallas_call`` megakernel (morph_fused.py). Validated with
-interpret=True on CPU.
+single-``pallas_call`` megakernel (morph_fused.py). Every entry point
+defaults to ``interpret=None``: compiled Mosaic on a TPU, the Pallas
+interpreter on CPU (``core.dispatch.resolve_interpret``).
 """
 from repro.kernels.fused_gradient import gradient_linear_sublane
 from repro.kernels.morph_fused import gradient2d_fused, morph2d_fused

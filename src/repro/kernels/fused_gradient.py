@@ -14,22 +14,26 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.types import MAX, MIN, Array, check_window
+from repro.core.dispatch import resolve_interpret
+from repro.core.types import MAX, MIN, Array, check_window, vmem_dtype
 
 
 def _gradient_kernel(xmin_ref, xmax_ref, o_ref, *, w: int):
     h = o_ref.shape[0]
-    lo = xmin_ref[0:h, :]
-    hi = xmax_ref[0:h, :]
+    dt = vmem_dtype(xmin_ref.dtype)
+    xmin = xmin_ref[...].astype(dt)
+    xmax = xmax_ref[...].astype(dt)
+    lo = xmin[0:h, :]
+    hi = xmax[0:h, :]
     for k in range(1, w):
-        lo = jnp.minimum(lo, xmin_ref[k : k + h, :])
-        hi = jnp.maximum(hi, xmax_ref[k : k + h, :])
+        lo = jnp.minimum(lo, xmin[k : k + h, :])
+        hi = jnp.maximum(hi, xmax[k : k + h, :])
     o_ref[...] = hi.astype(o_ref.dtype) - lo.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("w", "block_w", "interpret"))
 def gradient_linear_sublane(
-    x: Array, *, w: int, block_w: int = 128, interpret: bool = True
+    x: Array, *, w: int, block_w: int = 128, interpret: bool | None = None
 ) -> Array:
     """Fused (dilate - erode) of window ``w`` along axis -2 of a 2-D array.
 
@@ -37,6 +41,7 @@ def gradient_linear_sublane(
     differences overflow i8; unconditional widening keeps the semantics
     uniform), floats keep their dtype.
     """
+    interpret = resolve_interpret(interpret)
     w = check_window(w)
     if x.ndim != 2:
         raise ValueError("kernel operates on (H, W); vmap for batches")

@@ -11,7 +11,8 @@ i.e. four HBM traversals. This kernel restores the paper's structure:
   as one launch instead of ``vmap``-of-kernels;
 * per grid cell, a haloed ``(H + w_h - 1, BW + w_w - 1)`` strip is assembled
   in VMEM from the center block plus a narrow pre-gathered halo block
-  (``2 * wing_w`` columns per grid cell, built by one cheap XLA gather over
+  (``2 * wing_w`` columns per grid cell, rounded up to whole 128-lane
+  tiles, built by one cheap XLA gather over
   ~``2*wing_w/BW`` of the image), so each cell reads ``BW + w_w - 1``
   columns — not three full blocks, and not a second HBM traversal;
 * the sublane (H) pass runs first — linear ladder for small windows, vHGW
@@ -25,10 +26,16 @@ i.e. four HBM traversals. This kernel restores the paper's structure:
 HBM traffic per operator: ~(1 + w_w/BW) reads + 1 write versus 4 full
 read+write round trips for the two-pass + double-transpose path.
 
+Narrow integers (u8/u16/i8/i16) are widened to int32 once the blocks are
+in VMEM and stored back in their own dtype (``core.types.vmem_dtype``):
+Mosaic has no 8/16-bit integer min/max on TPU. Every block's last dimension
+is a whole number of 128-lane tiles or the array's full extent, which is
+Mosaic's block-shape rule; the halo block is padded up to whole lanes.
+
 VMEM budget per grid cell (see DESIGN.md §5): the (Hp, BW) center block,
-the (Hp, 2*wing_w) halo block, the assembled (Hp, BW + w_w - 1) strip, and
-the transposed (BW + w_w - 1, H) scratch; ``_pick_block_w`` sizes BW
-against a 12 MB soft budget.
+the (Hp, halo_cols) halo block, the assembled (Hp, BW + w_w - 1) strip, and
+the transposed (BW + w_w - 1, H) scratch, the last two at the widened
+compute width; ``_pick_block_w`` sizes BW against a 12 MB soft budget.
 """
 from __future__ import annotations
 
@@ -38,8 +45,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.dispatch import DispatchPolicy
-from repro.core.types import MAX, MIN, Array, as_op, check_window
+from repro.core.dispatch import DispatchPolicy, resolve_interpret
+from repro.core.types import MAX, MIN, Array, as_op, check_window, vmem_dtype
 from repro.kernels.morph_vhgw import _scan_segments
 
 
@@ -94,12 +101,17 @@ def _vmem_pass(block, w: int, op, neutral, method: str, n_out: int):
     return op.reduce(bwd[0:n_out, :], fwd[w - 1 : w - 1 + n_out, :])
 
 
-def _assemble_strip(xc, xh, wing_w: int):
-    """Haloed strip (Hp, BW + 2*wing_w) from the center block and the
-    narrow pre-gathered halo block (Hp, 2*wing_w): left wing first."""
-    if wing_w == 0:
+def _load_strip(refs, wing_w: int):
+    """Haloed strip (Hp, BW + 2*wing_w) of one padded view, widened to the
+    VMEM compute dtype: the center block, plus, when the SE has a W wing,
+    the lane-aligned halo block, whose first ``2*wing_w`` columns hold the
+    left wing then the right wing (the rest is alignment padding)."""
+    dt = vmem_dtype(refs[0].dtype)
+    xc = refs[0][0].astype(dt)
+    if not wing_w:
         return xc
-    return jnp.concatenate([xh[:, :wing_w], xc, xh[:, wing_w:]], axis=1)
+    xh = refs[1][0].astype(dt)
+    return jnp.concatenate([xh[:, :wing_w], xc, xh[:, wing_w : 2 * wing_w]], axis=1)
 
 
 def _fused_pipeline(strip, *, w_h, w_w, op, neutral, method_h, method_w, h_out):
@@ -111,68 +123,86 @@ def _fused_pipeline(strip, *, w_h, w_w, op, neutral, method_h, method_w, h_out):
     return z.T
 
 
-def _fused_kernel(xc_ref, xh_ref, o_ref, *, w_h, w_w, opname,
-                  method_h, method_w, wing_w):
+def _fused_kernel(*refs, w_h, w_w, opname, method_h, method_w, wing_w):
+    *ins, o_ref = refs
     op = as_op(opname)
-    neutral = op.neutral(xc_ref.dtype)
-    strip = _assemble_strip(xc_ref[0], xh_ref[0], wing_w)
-    o_ref[0] = _fused_pipeline(
-        strip, w_h=w_h, w_w=w_w, op=op, neutral=neutral,
+    out = _fused_pipeline(
+        _load_strip(ins, wing_w), w_h=w_h, w_w=w_w, op=op,
+        neutral=op.neutral(ins[0].dtype),
         method_h=method_h, method_w=method_w, h_out=o_ref.shape[1],
     )
+    o_ref[0] = out.astype(o_ref.dtype)
 
 
-def _gradient_kernel(nc_ref, nh_ref, xc_ref, xh_ref, o_ref, *,
-                     w_h, w_w, method_h, method_w, wing_w):
+def _gradient_kernel(*refs, w_h, w_w, method_h, method_w, wing_w):
     """Shared-load fused gradient: the min (erode) and max (dilate) pipelines
     run over the same haloed strip in one kernel; only the pad borders differ
     (each op needs its own neutral element), hence two padded views."""
+    *ins, o_ref = refs
+    n = len(ins) // 2
     h_out = o_ref.shape[1]
     e = _fused_pipeline(
-        _assemble_strip(nc_ref[0], nh_ref[0], wing_w),
-        w_h=w_h, w_w=w_w, op=MIN, neutral=MIN.neutral(nc_ref.dtype),
+        _load_strip(ins[:n], wing_w),
+        w_h=w_h, w_w=w_w, op=MIN, neutral=MIN.neutral(ins[0].dtype),
         method_h=method_h, method_w=method_w, h_out=h_out,
     )
     d = _fused_pipeline(
-        _assemble_strip(xc_ref[0], xh_ref[0], wing_w),
-        w_h=w_h, w_w=w_w, op=MAX, neutral=MAX.neutral(xc_ref.dtype),
+        _load_strip(ins[n:], wing_w),
+        w_h=w_h, w_w=w_w, op=MAX, neutral=MAX.neutral(ins[n].dtype),
         method_h=method_h, method_w=method_w, h_out=h_out,
     )
     o_ref[0] = d.astype(o_ref.dtype) - e.astype(o_ref.dtype)
 
 
+_LANES = 128  # TPU lane width: a block's last dim must be a multiple of it
+
+
+def _halo_cols(wing_w: int) -> int:
+    """Per-cell halo block width: both wings, rounded up to whole lanes."""
+    return -(-2 * wing_w // _LANES) * _LANES
+
+
 def _pad_for_grid(x, wing_h: int, wing_w: int, block_w: int, neutral):
-    """Neutral-pad (B, H, W) to (B, Hp, gw * BW) plus a narrow pre-gathered
-    halo array (B, Hp, gw * 2 * wing_w) holding, for each column block, its
-    left wing then its right wing. The halo gather is one cheap XLA pass over
+    """Neutral-pad (B, H, W) into the kernel's blocked inputs.
+
+    Returns ``(arrays, in_specs, gw)``: the (B, Hp, gw * BW) center array,
+    and, when the SE has a W wing, a halo array (B, Hp, gw * halo_cols)
+    holding for each column block its left wing, its right wing, and
+    padding up to whole lanes (Mosaic's block-shape rule). The halo is
+    gathered by two strided reshapes, one cheap XLA pass over
     ~2*wing_w/BW of the image, and it is what lets every grid cell read
-    BW + 2*wing_w columns instead of three full blocks. Returns
-    (padded, halo, gw)."""
+    BW + 2*wing_w columns instead of three full blocks.
+    """
     b, _, wid = x.shape
-    pw = -wid % block_w
-    gw = (wid + pw) // block_w
+    gw = -(-wid // block_w)
+    pw = gw * block_w - wid
     xp = jnp.pad(
         x,
         ((0, 0), (wing_h, wing_h), (wing_w, pw + wing_w)),
         constant_values=neutral,
     )
     hp = xp.shape[1]
-    if wing_w == 0:
-        # degenerate 1-col dummy so the BlockSpec stays well-formed
-        return xp, jnp.zeros((b, hp, gw), xp.dtype), gw
-    left = jnp.stack(
-        [xp[:, :, j * block_w : j * block_w + wing_w] for j in range(gw)], axis=2
-    )
-    right = jnp.stack(
-        [
-            xp[:, :, wing_w + (j + 1) * block_w : wing_w + (j + 1) * block_w + wing_w]
-            for j in range(gw)
-        ],
-        axis=2,
-    )
-    halo = jnp.concatenate([left, right], axis=-1).reshape(b, hp, gw * 2 * wing_w)
     core = xp[:, :, wing_w : wing_w + gw * block_w]
-    return core, halo, gw
+    specs = [pl.BlockSpec((1, hp, block_w), lambda bi, j: (bi, 0, j))]
+    if wing_w == 0:
+        return [core], specs, gw
+
+    def wings(v):  # the first wing_w columns of each BW-wide block of v
+        return v.reshape(b, hp, gw, block_w)[..., :wing_w]
+
+    # block j's left wing starts at padded column j*BW, its right wing at
+    # wing_w + (j+1)*BW; both read whole BW-blocks (wing_w <= BW)
+    left = wings(xp[:, :, : gw * block_w])
+    right = wings(jnp.pad(xp[:, :, wing_w + block_w :],
+                          ((0, 0), (0, 0), (0, block_w - wing_w))))
+    cols = _halo_cols(wing_w)
+    halo = jnp.pad(
+        jnp.concatenate([left, right], axis=-1),
+        ((0, 0), (0, 0), (0, 0), (0, cols - 2 * wing_w)),
+        constant_values=neutral,
+    ).reshape(b, hp, gw * cols)
+    specs.append(pl.BlockSpec((1, hp, cols), lambda bi, j: (bi, 0, j)))
+    return [core, halo], specs, gw
 
 
 _VMEM_SOFT_BUDGET = 12 * 2**20  # leave headroom under the ~16 MB/core VMEM
@@ -185,11 +215,16 @@ def fused_supports(se) -> bool:
     return (check_window(se[1]) - 1) // 2 <= _MAX_AUTO_BLOCK_W
 
 
-def _pick_block_w(wing_w: int, h: int, w_h: int, itemsize: int) -> int:
+def _pick_block_w(wing_w: int, h: int, w_h: int, dtype, strips: int) -> int:
     """Auto block width: widen the strip until the W-halo overhead
     ((BW + w_w - 1) / BW) is small, then shrink back while the estimated
-    VMEM working set exceeds the soft budget (DESIGN.md §5)."""
-    min_bw = 128
+    VMEM working set of ``strips`` pipelines exceeds the soft budget
+    (DESIGN.md §5). Input blocks count at the input itemsize
+    (double-buffered); the strip and its transpose count at the widened
+    compute width (``core.types.vmem_dtype``)."""
+    itemsize = strips * jnp.dtype(dtype).itemsize
+    vmem_itemsize = strips * vmem_dtype(dtype).itemsize
+    min_bw = _LANES
     while min_bw < wing_w:  # correctness floor: the halo must fit one block
         min_bw *= 2
     bw = min_bw
@@ -198,7 +233,8 @@ def _pick_block_w(wing_w: int, h: int, w_h: int, itemsize: int) -> int:
     while bw > min_bw:
         hp = h + w_h - 1
         strip_w = bw + 2 * wing_w
-        est = (hp * (bw + 2 * wing_w + strip_w) + 2 * strip_w * h) * itemsize
+        est = (2 * hp * (bw + _halo_cols(wing_w)) * itemsize
+               + (hp * strip_w + 2 * strip_w * h) * vmem_itemsize)
         if est <= _VMEM_SOFT_BUDGET:
             break
         bw //= 2
@@ -227,13 +263,15 @@ def morph2d_fused(
     method: str = "auto",
     policy: DispatchPolicy | None = None,
     block_w: int | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> Array:
     """Separable 2-D erosion/dilation as a single ``pallas_call``.
 
     ``x`` is ``(H, W)`` or ``(B, H, W)``; batches run as a leading grid
-    dimension, not ``vmap``-of-kernels.
+    dimension, not ``vmap``-of-kernels. ``interpret=None`` resolves through
+    ``core.dispatch.resolve_interpret`` (compiled on TPU).
     """
+    interpret = resolve_interpret(interpret, policy)
     w_h, w_w = _check_fusable(se, block_w)
     mop = as_op(op)
     if x.ndim == 2:
@@ -248,25 +286,22 @@ def morph2d_fused(
     b, h, wid = x.shape
     wing_h, wing_w = (w_h - 1) // 2, (w_w - 1) // 2
     if block_w is None:
-        block_w = _pick_block_w(wing_w, h, w_h, jnp.dtype(x.dtype).itemsize)
+        block_w = _pick_block_w(wing_w, h, w_h, x.dtype, strips=1)
     method_h, method_w = _resolve_methods((w_h, w_w), method, policy, x.dtype)
-    core, halo, gw = _pad_for_grid(x, wing_h, wing_w, block_w, mop.neutral(x.dtype))
-    hp = h + 2 * wing_h
-    halo_cols = halo.shape[-1] // gw
+    arrays, specs, gw = _pad_for_grid(
+        x, wing_h, wing_w, block_w, mop.neutral(x.dtype)
+    )
     out = pl.pallas_call(
         functools.partial(
             _fused_kernel, w_h=w_h, w_w=w_w, opname=mop.name,
             method_h=method_h, method_w=method_w, wing_w=wing_w,
         ),
         grid=(b, gw),
-        in_specs=[
-            pl.BlockSpec((1, hp, block_w), lambda bi, j: (bi, 0, j)),
-            pl.BlockSpec((1, hp, halo_cols), lambda bi, j: (bi, 0, j)),
-        ],
+        in_specs=specs,
         out_specs=pl.BlockSpec((1, h, block_w), lambda bi, j: (bi, 0, j)),
         out_shape=jax.ShapeDtypeStruct((b, h, gw * block_w), x.dtype),
         interpret=interpret,
-    )(core, halo)
+    )(*arrays)
     return out[:, :, :wid]
 
 
@@ -281,7 +316,7 @@ def gradient2d_fused(
     method: str = "auto",
     policy: DispatchPolicy | None = None,
     block_w: int | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> Array:
     """Fused 2-D morphological gradient (dilate - erode) in one launch.
 
@@ -291,6 +326,7 @@ def gradient2d_fused(
     for two-pass dilate/erode plus the subtraction. Integer inputs widen to
     int32 (i8 differences overflow i8), floats keep their dtype.
     """
+    interpret = resolve_interpret(interpret, policy)
     w_h, w_w = _check_fusable(se, block_w)
     if x.ndim == 2:
         return gradient2d_fused(
@@ -305,24 +341,24 @@ def gradient2d_fused(
     b, h, wid = x.shape
     wing_h, wing_w = (w_h - 1) // 2, (w_w - 1) // 2
     if block_w is None:
-        # gradient holds two strips (min and max pipelines): halve the budget
-        block_w = _pick_block_w(wing_w, h, w_h, 2 * jnp.dtype(x.dtype).itemsize)
+        # gradient holds two strips (min and max pipelines)
+        block_w = _pick_block_w(wing_w, h, w_h, x.dtype, strips=2)
     method_h, method_w = _resolve_methods((w_h, w_w), method, policy, x.dtype)
-    core_min, halo_min, gw = _pad_for_grid(x, wing_h, wing_w, block_w, MIN.neutral(x.dtype))
-    core_max, halo_max, _ = _pad_for_grid(x, wing_h, wing_w, block_w, MAX.neutral(x.dtype))
-    hp = h + 2 * wing_h
-    halo_cols = halo_min.shape[-1] // gw
-    core_spec = pl.BlockSpec((1, hp, block_w), lambda bi, j: (bi, 0, j))
-    halo_spec = pl.BlockSpec((1, hp, halo_cols), lambda bi, j: (bi, 0, j))
+    arrays_min, specs, gw = _pad_for_grid(
+        x, wing_h, wing_w, block_w, MIN.neutral(x.dtype)
+    )
+    arrays_max, _, _ = _pad_for_grid(
+        x, wing_h, wing_w, block_w, MAX.neutral(x.dtype)
+    )
     out = pl.pallas_call(
         functools.partial(
             _gradient_kernel, w_h=w_h, w_w=w_w,
             method_h=method_h, method_w=method_w, wing_w=wing_w,
         ),
         grid=(b, gw),
-        in_specs=[core_spec, halo_spec, core_spec, halo_spec],
+        in_specs=specs + specs,
         out_specs=pl.BlockSpec((1, h, block_w), lambda bi, j: (bi, 0, j)),
         out_shape=jax.ShapeDtypeStruct((b, h, gw * block_w), out_dtype),
         interpret=interpret,
-    )(core_min, halo_min, core_max, halo_max)
+    )(*arrays_min, *arrays_max)
     return out[:, :, :wid]

@@ -19,18 +19,21 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.types import Array, as_op, check_window
+from repro.core.dispatch import resolve_interpret
+from repro.core.types import Array, as_op, check_window, vmem_dtype
 
 
 def _linear_kernel(x_ref, o_ref, *, w: int, opname: str):
     op = as_op(opname)
     h = o_ref.shape[0]
     # Paper's inner loop: a single accumulator reduced against w shifted
-    # loads; slices along sublanes are offset reads of the same VMEM block.
-    val = x_ref[0:h, :]
+    # loads; slices along sublanes are offset reads of the same VMEM block,
+    # widened once to the compute dtype (core.types.vmem_dtype).
+    x = x_ref[...].astype(vmem_dtype(x_ref.dtype))
+    val = x[0:h, :]
     for k in range(1, w):
-        val = op.reduce(val, x_ref[k : k + h, :])
-    o_ref[...] = val
+        val = op.reduce(val, x[k : k + h, :])
+    o_ref[...] = val.astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -42,9 +45,10 @@ def morph_linear_sublane(
     w: int,
     op: str = "min",
     block_w: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> Array:
     """Running min/max of window ``w`` along axis -2 of a 2-D array."""
+    interpret = resolve_interpret(interpret)
     w = check_window(w)
     mop = as_op(op)
     if x.ndim != 2:

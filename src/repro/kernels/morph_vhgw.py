@@ -12,7 +12,8 @@ Paper §5.1.1 baseline, adapted to TPU (DESIGN.md §2):
   Per-pixel cost: ~2*ceil(log2 w) + 1 vector ops — still O(1)-ish in w and
   independent of window *position*, preserving the paper's key property.
 
-VMEM budget: 3 copies of the (ceil((H+w-1)/w)*w, BW) strip.
+VMEM budget: 3 copies of the (ceil((H+w-1)/w)*w, BW) strip, at the
+widened compute width (``core.types.vmem_dtype``).
 """
 from __future__ import annotations
 
@@ -22,7 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.types import Array, as_op, check_window
+from repro.core.dispatch import resolve_interpret
+from repro.core.types import Array, as_op, check_window, vmem_dtype
 
 
 def _scan_segments(segs, op, neutral, reverse: bool):
@@ -52,11 +54,12 @@ def _vhgw_kernel(x_ref, o_ref, *, w: int, opname: str, nseg: int):
     neutral = op.neutral(x_ref.dtype)
     h = o_ref.shape[0]
     bw = o_ref.shape[1]
-    segs = x_ref[...].reshape(nseg, w, bw)
+    segs = x_ref[...].astype(vmem_dtype(x_ref.dtype)).reshape(nseg, w, bw)
     fwd = _scan_segments(segs, op, neutral, reverse=False).reshape(nseg * w, bw)
     bwd = _scan_segments(segs, op, neutral, reverse=True).reshape(nseg * w, bw)
     # out[i] = op(B[i], F[i + w - 1]): window [i, i+w-1] spans <= 2 segments.
-    o_ref[...] = op.reduce(bwd[0:h, :], fwd[w - 1 : w - 1 + h, :])
+    out = op.reduce(bwd[0:h, :], fwd[w - 1 : w - 1 + h, :])
+    o_ref[...] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("w", "op", "block_w", "interpret"))
@@ -66,9 +69,10 @@ def morph_vhgw_sublane(
     w: int,
     op: str = "min",
     block_w: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> Array:
     """vHGW running min/max of window ``w`` along axis -2 of a 2-D array."""
+    interpret = resolve_interpret(interpret)
     w = check_window(w)
     mop = as_op(op)
     if x.ndim != 2:

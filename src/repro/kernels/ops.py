@@ -149,6 +149,12 @@ def raw_morph2d(
     policy, the SE, and the per-node cost model allow; two-pass + transpose
     pipeline otherwise."""
     interpret = resolve_interpret(interpret, policy)
+    if x.dtype == jnp.bool_:
+        # Mosaic has no boolean blocks; min/max on the 0/1 u8 image is the
+        # boolean AND/OR bit for bit, so widen once here for both routes
+        return raw_morph2d(
+            x.astype(jnp.uint8), se, op, policy=policy, interpret=interpret
+        ).astype(jnp.bool_)
     if (
         policy.fused_2d
         and fused_supports(se)

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.dispatch import resolve_interpret
 from repro.core.types import Array
 
 
@@ -34,13 +35,16 @@ def _transpose_kernel(x_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def transpose_tiled(x: Array, *, tile: int = 128, interpret: bool = True) -> Array:
+def transpose_tiled(
+    x: Array, *, tile: int = 128, interpret: bool | None = None
+) -> Array:
     """Transpose the last two dims of ``x`` with an explicitly tiled kernel.
 
     ``tile`` is the square VMEM block edge; 128 matches the TPU lane width
     (the paper's "8" / "16" matched the NEON register width in elements).
     Non-multiple shapes are padded and cropped.
     """
+    interpret = resolve_interpret(interpret)
     *lead, h, w = x.shape
     if lead:
         flat = x.reshape((-1, h, w))

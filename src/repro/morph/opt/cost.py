@@ -105,11 +105,10 @@ def fit_affine(points) -> tuple[float, float]:
 
 
 def device_kind() -> str:
-    """Cost tables are keyed by this (e.g. ``cpu``, ``TPU v4``)."""
-    try:
-        return str(jax.devices()[0].device_kind)
-    except Exception:  # pragma: no cover - no backend at all
-        return jax.default_backend()
+    """Cost tables are keyed by this (e.g. ``cpu``, ``TPU v5 lite``). A
+    backend that fails to start raises: a table keyed by a guess would
+    steer dispatch on the wrong device."""
+    return str(jax.devices()[0].device_kind)
 
 
 @dataclasses.dataclass(frozen=True)

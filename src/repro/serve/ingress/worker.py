@@ -40,6 +40,8 @@ which prints ``INGRESS_WORKER_READY <host> <port>`` once serving;
 from __future__ import annotations
 
 import argparse
+import collections
+import glob
 import json
 import os
 import socket
@@ -48,6 +50,7 @@ import sys
 import threading
 import time
 
+from repro.compile_cache import enable_compile_cache
 from repro.serve.ingress import proto
 from repro.serve.morph.resilience import FaultPlan, ServiceClosed
 from repro.serve.morph.service import MorphService, ServiceConfig
@@ -365,18 +368,68 @@ def config_from_json(d: dict) -> ServiceConfig:
     return ServiceConfig(**kw)
 
 
+def _local_tpu_chips() -> int:
+    """TPU chips on this host, counted from their device nodes: the parent
+    must not load the TPU runtime to ask, or it would hold every chip."""
+    return len(glob.glob("/dev/accel[0-9]*")) or len(
+        glob.glob("/dev/vfio/[0-9]*")
+    )
+
+
+def _free_port(host: str = "127.0.0.1") -> int:
+    with socket.socket() as s:
+        s.bind((host, 0))
+        return s.getsockname()[1]
+
+
+def chip_env(worker_id: int, env: dict) -> dict:
+    """Environment that gives worker ``worker_id`` TPU chip ``worker_id``
+    and no other, before the child imports JAX; empty when the child will
+    not hold a chip (no chips here, or ``JAX_PLATFORMS`` leaves TPU out).
+
+    A chip belongs to one process at a time, so a worker id past the last
+    chip is refused rather than left to fail or hang on the chip's lock.
+    """
+    platforms = env.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return {}
+    chips = _local_tpu_chips()
+    if chips == 0:
+        return {}
+    if not 0 <= worker_id < chips:
+        raise ValueError(
+            f"ingress worker {worker_id} would hold a TPU chip, but this "
+            f"host has {chips}: one chip-holding worker per chip, ids "
+            f"0..{chips - 1}"
+        )
+    port = _free_port()
+    return {
+        "TPU_VISIBLE_CHIPS": str(worker_id),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_PORT": str(port),
+        "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+    }
+
+
+_STDERR_TAIL_LINES = 40
+
+
 def spawn_worker(config: dict | None = None, *, worker_id: int = 0,
                  host: str = "127.0.0.1", env: dict | None = None,
                  timeout: float = 120.0):
     """Launch a worker subprocess and wait for its READY handshake.
     Returns ``(Popen, (host, port))``. The child inherits this process's
     environment (plus ``PYTHONPATH`` pointing at this repro checkout, so
-    callers don't have to re-derive it)."""
+    callers don't have to re-derive it, and on a TPU host the variables
+    that give it chip ``worker_id`` alone, see :func:`chip_env`). A
+    failed start raises with the tail of the child's stderr."""
     src_root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.dirname(os.path.abspath(__file__)))))
     child_env = dict(os.environ)
     if env:
         child_env.update(env)
+    child_env.update(chip_env(worker_id, child_env))
     pp = child_env.get("PYTHONPATH", "")
     if src_root not in pp.split(os.pathsep):
         child_env["PYTHONPATH"] = (
@@ -388,23 +441,38 @@ def spawn_worker(config: dict | None = None, *, worker_id: int = 0,
         [sys.executable, "-m", "repro.serve.ingress.worker",
          "--host", host, "--config", json.dumps(cfg),
          "--worker-id", str(worker_id)],
-        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE,
         text=True, env=child_env,
     )
+    # drain stderr for the worker's whole life (a full pipe would block
+    # it), keeping the last lines for the error below
+    tail: collections.deque = collections.deque(maxlen=_STDERR_TAIL_LINES)
+    drain = threading.Thread(
+        target=tail.extend, args=(proc.stderr,),
+        name=f"ingress-worker-{worker_id}-stderr", daemon=True,
+    )
+    drain.start()
+
+    def failed(what: str) -> RuntimeError:
+        proc.kill()
+        proc.wait(timeout=30)
+        drain.join(timeout=5)
+        detail = "".join(tail).rstrip()
+        return RuntimeError(
+            f"ingress worker {worker_id} {what} (returncode {proc.returncode})"
+            + (f"; stderr tail:\n{detail}" if detail else "")
+        )
+
     deadline = time.monotonic() + timeout
     while True:
         line = proc.stdout.readline()
         if not line:
-            raise RuntimeError(
-                f"ingress worker {worker_id} exited before READY "
-                f"(returncode {proc.poll()})"
-            )
+            raise failed("exited before READY")
         if line.startswith(READY_SENTINEL):
             _, h, p = line.split()
             return proc, (h, int(p))
         if time.monotonic() > deadline:
-            proc.kill()
-            raise RuntimeError(f"ingress worker {worker_id} READY timeout")
+            raise failed("READY timeout")
 
 
 def main(argv=None) -> int:
@@ -417,6 +485,7 @@ def main(argv=None) -> int:
     ap.add_argument("--sharded", action="store_true",
                     help="wrap a ShardedMorphService over all local devices")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     cfg = config_from_json(json.loads(args.config))
     if args.sharded:
         from repro.shard.router import ShardedMorphService

@@ -650,6 +650,13 @@ class MorphService:
             else:
                 self._execute_bucketed(key, reqs)
 
+    def _count_device(self, outs: dict) -> None:
+        """Count the dispatch under the device its outputs landed on
+        (``device.<id>.dispatches``): what shows, from the metrics, that a
+        pinned shard's work stays on its own chip."""
+        for d in next(iter(outs.values())).devices():
+            self.metrics.counter(f"device.{d.id}.dispatches").inc()
+
     def _record_aux(self, aux: dict) -> None:
         budget = int(aux["iters_budget"])
         if budget:
@@ -683,6 +690,7 @@ class MorphService:
             with span, (obs.dispatch_annotation(plan.name) if obs is not None
                         else contextlib.nullcontext()):
                 outs, aux = execute(jnp.asarray(batch), jnp.asarray(rects))
+                self._count_device(outs)
                 # np.asarray blocks until ready: the executor span covers
                 # dispatch + device run, not just the enqueue
                 outs = {k: np.asarray(v) for k, v in outs.items()}
@@ -735,6 +743,7 @@ class MorphService:
             def execute(tiles, rects):
                 fn = self._executor_for(r.plan, ext, tiles.dtype, tiles.shape[0])
                 outs, aux = fn(jnp.asarray(tiles), jnp.asarray(rects))
+                self._count_device(outs)
                 aux_chunks.append(aux)  # record after all chunks dispatch:
                 return outs             # int(aux) here would sync per launch
 

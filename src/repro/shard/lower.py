@@ -38,7 +38,7 @@ from typing import Literal
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.dispatch import DispatchPolicy, morph_1d
@@ -217,7 +217,7 @@ def to_sharded(
             local, mesh=mesh,
             in_specs=({k: spec for k in env_p},),
             out_specs={k: spec for k in outs},
-            check_rep=False,
+            check_vma=False,
         )
         res = run(env_p)
         crop = (Ellipsis, slice(0, h), slice(0, w))

@@ -56,7 +56,12 @@ def image_mesh(shards: "int | tuple[int, int] | None" = None):
             f"{available_shards()} available (set XLA_FLAGS="
             f"--xla_force_host_platform_device_count=N to emulate on CPU)"
         )
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the lowering places data with shard_map specs and crops the
+    # padded global result afterwards, which explicit-axis sharding types
+    # (JAX's make_mesh default) refuse for extents the mesh does not divide
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def mesh_axis_sizes(mesh) -> tuple[int, int]:

@@ -41,10 +41,11 @@ def _iter_jaxprs(jaxpr):
         for val in eqn.params.values():
             vals = val if isinstance(val, (list, tuple)) else [val]
             for v in vals:
-                if isinstance(v, jax.core.ClosedJaxpr):
-                    yield from _iter_jaxprs(v.jaxpr)
-                elif isinstance(v, jax.core.Jaxpr):
-                    yield from _iter_jaxprs(v)
+                # duck-typed: a ClosedJaxpr wraps its Jaxpr in ``.jaxpr``,
+                # and the classes' import path moves between JAX releases
+                inner = getattr(v, "jaxpr", v)
+                if hasattr(inner, "eqns"):
+                    yield from _iter_jaxprs(inner)
 
 
 def count_pallas_calls(fn, *args) -> int:
@@ -130,6 +131,73 @@ def test_batched_two_pass_fallback():
     xb = rand((3, 40, 70), np.uint8)
     got = np.asarray(erode2d_tpu(xb, (3, 5), fused=False))
     np.testing.assert_array_equal(got, np.asarray(morph2d_naive(xb, (3, 5), op="min")))
+
+
+# ------------------------------------- VMEM widening and lane-aligned halo
+# SE widths 1, 3, 121, 127 give wing_w 0, 1, 60, 63; the image widths are
+# not multiples of 128, so the last column block is partly padding.
+@pytest.mark.parametrize("dtype", [np.uint8, np.uint16, np.int8, np.int16])
+@pytest.mark.parametrize("w_w", [1, 3, 121, 127])
+def test_fused_narrow_ints_vs_naive(dtype, w_w):
+    x = rand((2, 37, 260), dtype)
+    se = (5, w_w)
+    for op in ("min", "max"):
+        got = morph2d_fused(x, se, op=op)
+        assert got.dtype == dtype
+        np.testing.assert_array_equal(
+            np.asarray(got), np.asarray(morph2d_naive(x, se, op=op))
+        )
+
+
+@pytest.mark.parametrize("dtype", [np.uint8, np.uint16, np.int8, np.int16])
+@pytest.mark.parametrize("w_w", [1, 3, 121, 127])
+def test_fused_gradient_narrow_ints_vs_ref(dtype, w_w):
+    x = rand((29, 200), dtype)
+    got = gradient2d_fused(x, (3, w_w))
+    want = gradient2d_ref(x, (3, w_w))
+    assert got.dtype == want.dtype == np.int32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("fused", [True, False])
+@pytest.mark.parametrize("w_w", [1, 3, 121, 127])
+def test_bool_mask_widens_once(fused, w_w):
+    x = jnp.asarray(RNG.random((2, 24, 150)) < 0.7)
+    for fn, op in ((erode2d_tpu, "min"), (dilate2d_tpu, "max")):
+        got = fn(x, (3, w_w), fused=fused)
+        assert got.dtype == np.bool_
+        np.testing.assert_array_equal(
+            np.asarray(got), np.asarray(morph2d_naive(x, (3, w_w), op=op))
+        )
+
+
+@pytest.mark.parametrize("narrow,wide", [
+    (np.uint16, np.uint32), (np.int8, np.int32), (np.int16, np.int32),
+])
+def test_two_pass_narrow_ints_match_wide(narrow, wide):
+    # the two-pass kernels widen in VMEM too; the result must equal the
+    # same image run in a wide dtype, cast back
+    x = rand((40, 140), narrow)
+    for se in ((3, 5), (35, 3)):  # linear ladder and vHGW
+        got = erode2d_tpu(x, se, fused=False)
+        assert got.dtype == narrow
+        want = erode2d_tpu(x.astype(wide), se, fused=False).astype(narrow)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("wing_w", [0, 1, 60, 63])
+def test_fused_blocks_are_lane_aligned(wing_w):
+    # Mosaic's block-shape rule: the last block dim is a whole number of
+    # 128-lane tiles or the array's full extent
+    from repro.kernels.morph_fused import _pad_for_grid
+
+    x = jnp.zeros((2, 30, 300), jnp.uint8)
+    arrays, specs, gw = _pad_for_grid(x, 2, wing_w, 128, 0)
+    assert len(arrays) == (2 if wing_w else 1) == len(specs)
+    for arr, spec in zip(arrays, specs):
+        last = spec.block_shape[-1]
+        assert last % 128 == 0 or last == arr.shape[-1]
+        assert arr.shape[-1] == gw * last
 
 
 # ------------------------------------------------------------ fused gradient

@@ -25,6 +25,7 @@ frames, real reader threads) so the suite is tier-1; the one true
 multi-*process* test (``spawn_worker`` fleet) is marked ``slow`` and runs
 in the ingress CI job.
 """
+import concurrent.futures
 import socket
 import threading
 import time
@@ -409,9 +410,12 @@ def test_close_resolves_every_future_exactly_once():
         assert not closer.is_alive()
         assert host.wait_closed(10)
 
+    # every response was written before the host closed; the client's
+    # reader thread may still be delivering the last ones
+    _, not_done = concurrent.futures.wait(early + late, timeout=30)
+    assert not not_done
     results, closed_errs = 0, 0
     for f in early + late:
-        assert f.done()
         exc = f.exception(timeout=0)
         if exc is None:
             assert isinstance(f.result(), np.ndarray)
@@ -648,6 +652,30 @@ def test_subprocess_fleet_round_trip_and_kill():
         for p in procs:
             p.kill()
             p.wait(timeout=30)
+
+
+def test_chip_env_one_chip_per_worker(monkeypatch):
+    from repro.serve.ingress import worker
+
+    monkeypatch.setattr(worker, "_local_tpu_chips", lambda: 2)
+    env = worker.chip_env(1, {})
+    assert env["TPU_VISIBLE_CHIPS"] == "1"
+    assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+    assert env["TPU_PROCESS_ADDRESSES"] == f"localhost:{env['TPU_PROCESS_PORT']}"
+    with pytest.raises(ValueError, match="one chip-holding worker per chip"):
+        worker.chip_env(2, {})
+    # a worker kept off the TPU holds no chip, so any id is fine
+    assert worker.chip_env(7, {"JAX_PLATFORMS": "cpu"}) == {}
+    monkeypatch.setattr(worker, "_local_tpu_chips", lambda: 0)
+    assert worker.chip_env(7, {}) == {}
+
+
+def test_spawn_worker_failure_shows_stderr_tail():
+    # the child raises while building its service; the parent's error
+    # carries the child's own traceback instead of a bare "exited"
+    with pytest.raises(RuntimeError, match="backend must be one of"):
+        spawn_worker({"backend": "no-such-backend"}, worker_id=0,
+                     env={"JAX_PLATFORMS": "cpu"}, timeout=120)
 
 
 def test_config_from_json_maps_and_ignores_unknowns():

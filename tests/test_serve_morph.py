@@ -355,6 +355,24 @@ def test_resolve_interpret_precedence(monkeypatch):
     assert resolve_interpret(None, DispatchPolicy(interpret=True)) is True
 
 
+def test_resolve_interpret_refuses_interpreter_on_tpu(monkeypatch):
+    # on a TPU backend the kernels compile; interpret mode from any source
+    # is an error instead of a silent slow path that hides the device
+    from repro.core import dispatch
+
+    monkeypatch.setattr(dispatch.jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    assert resolve_interpret(None) is False
+    assert resolve_interpret(False) is False
+    with pytest.raises(ValueError, match="TPU"):
+        resolve_interpret(True)
+    with pytest.raises(ValueError, match="TPU"):
+        resolve_interpret(None, DispatchPolicy(interpret=True))
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    with pytest.raises(ValueError, match="TPU"):
+        resolve_interpret(None)
+
+
 def test_custom_plan_registration_and_multi_output():
     plan = Plan(
         "open_then_edges",

@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.morph import (
@@ -98,7 +98,7 @@ def test_exchange_halo_contents(n, wing):
 
     ext = shard_map(
         local, mesh=mesh, in_specs=P("rows", None),
-        out_specs=P("rows", None), check_rep=False,
+        out_specs=P("rows", None), check_vma=False,
     )(x)
     ext = np.asarray(ext).reshape(n, rows + 2 * wing, 8)
     padded = np.full((wing + rows * n + wing, 8), neutral, dtype=np.uint8)
@@ -126,7 +126,7 @@ def test_exchange_halo_multi_hop(n):
 
     ext = np.asarray(
         shard_map(local, mesh=mesh, in_specs=P("rows", None),
-                  out_specs=P("rows", None), check_rep=False)(x)
+                  out_specs=P("rows", None), check_vma=False)(x)
     ).reshape(n, rows + 2 * wing, 4)
     padded = np.full((wing + rows * n + wing, 4), neutral, dtype=np.uint8)
     padded[wing:-wing] = x
@@ -349,6 +349,10 @@ def test_router_uses_all_devices_and_merges_stats():
         for f in futs:
             f.result()
         stats = svc.stats()
+        # a shard's dispatches run on its pinned device and no other
+        for shard in svc.shards:
+            seen = {k for k in shard.metrics_snapshot() if k.startswith("device.")}
+            assert seen <= {f"device.{shard.config.device.id}.dispatches"}
     assert stats["shards"] == N_DEV
     assert stats["requests"] == len(reqs)
     assert len(stats["per_shard"]) == N_DEV

@@ -18,6 +18,7 @@ fixes that ride with them:
   deadlines (the ``("rle", plan, dtype)`` regression).
 """
 import math
+import threading
 import time
 from concurrent.futures import Future
 
@@ -237,30 +238,47 @@ def test_batcher_fair_order_under_flood():
     light one — the light tenant's requests complete interleaved, not
     parked behind the whole flood."""
     order = []
+    entered, release = threading.Event(), threading.Event()
 
     def execute(key, reqs):
+        if key == "gate":
+            entered.set()
+            release.wait(timeout=30)
         for r in reqs:
-            order.append(r.tenant)
+            if key != "gate":
+                order.append(r.tenant)
             r.future.set_result(True)
 
-    # max_batch > group size: groups pend until the window expires, so the
-    # whole flood is due at once and dispatch order is the scheduler's
+    # max_batch > group size, so no group fills. The worker is held inside
+    # a first dispatch while the flood queues behind close(); the drain
+    # then makes the whole flood due at once, and dispatch order is the
+    # scheduler's alone, not a race between window timers
     b = MicroBatcher(execute, max_batch=4, window_s=0.05,
                      tenants={"whale": TenantQuota(weight=1.0),
                               "shrimp": TenantQuota(weight=1.0)},
                      retry=RetryPolicy(max_retries=0))
+    closer = threading.Thread(target=b.close)
     try:
+        b.submit(Req(key="gate"))
+        assert entered.wait(timeout=30)
         reqs = []
-        # distinct keys -> one group per request, all due at once
+        # distinct keys -> one group per request
         for i in range(20):
             reqs.append(Req(key=f"w{i}", tenant="whale"))
         for i in range(4):
             reqs.append(Req(key=f"s{i}", tenant="shrimp"))
         for r in reqs:
             b.submit(r)
+        closer.start()
+        deadline = time.monotonic() + 30
+        while not b._closed and time.monotonic() < deadline:
+            time.sleep(0.001)
+        release.set()
+        closer.join(timeout=30)
         for r in reqs:
             assert r.future.result(timeout=30)
     finally:
+        release.set()
         b.close()
     # equal weights: shrimp's 4 must all land within the first ~half of the
     # dispatch order, not after the 20-deep whale flood
