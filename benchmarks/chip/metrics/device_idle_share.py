@@ -1,0 +1,10 @@
+"""Share of the traced window in which no operation ran on the cell's chips:
+1 - the union of the device operations' intervals over the window, averaged
+over the chips (profiler trace)."""
+
+
+def read(run):
+    t = run.trace
+    if t is None or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])

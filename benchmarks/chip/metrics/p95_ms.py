@@ -1,0 +1,8 @@
+"""95th percentile of client latency from when each request was due, over
+every request due in the window (host clock); a failed request misses."""
+import numpy as np
+
+
+def read(run):
+    lat = run.latencies_ms()
+    return float(np.percentile(lat, 95)) if lat else None
