@@ -6,15 +6,15 @@ Two claims to hold the obs subsystem to:
   the BENCH_serve traffic mix at the same img/s as before the subsystem
   existed — every hook site is one ``is None`` check. Measured as an A/A
   ratio between two disabled passes (the noise floor) reported next to it.
-* **Enabled is cheap.** ``obs=ObsConfig()`` (tracing + executor profiling)
+* **Enabled is cheap.** ``obs=ObsConfig()`` (spans and their stages)
   must cost <= ~5% on the same mix — spans are two ``perf_counter`` calls
   and a deque append per pipeline stage.
 
 Plus the acceptance scenario: a chaos replay (one shard's dispatches
 failing, one poison request, on logical shards) with obs enabled must
 export Chrome trace-event JSON that passes ``validate_chrome_trace``,
-contains the full resilience span vocabulary (queue / dispatch / executor /
-retry / hop / failover), and closes every span exactly once.
+contains the full resilience span vocabulary (queue / dispatch / retry /
+hop / failover), and closes every span exactly once.
 
 Emits ``benchmarks/results/BENCH_obs.json`` and the chaos trace itself as
 ``benchmarks/results/trace_obs_chaos.json`` (drop it into ui.perfetto.dev).
@@ -52,7 +52,7 @@ TRACE_OUT = os.path.join(
 # adds "hop"/"failover"; the batcher adds "retry"; "bisect" appears only
 # when a poison hides inside a multi-request group).
 REQUIRED_CHAOS_SPANS = {
-    "queue", "dispatch", "executor", "retry", "hop", "failover",
+    "queue", "dispatch", "retry", "hop", "failover",
 }
 
 
@@ -115,7 +115,7 @@ def bench_overhead(quick: bool = False, repeats: int = 3) -> list[dict]:
             "disabled_aa_ratio": round(
                 best["off_a"][0] / best["off_b"][0], 4
             ) if best["off_b"][0] else None,
-            # enabled overhead: how much slower tracing+profiling makes it
+            # enabled overhead: how much slower spans and stages make it
             "enabled_overhead": round(off_ips / on_ips, 4) if on_ips else None,
             "off_p99_ms": round(best["off_a"][1]["p99_ms"], 2),
             "on_p99_ms": round(best["on"][1]["p99_ms"], 2),

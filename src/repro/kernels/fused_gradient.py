@@ -66,5 +66,6 @@ def gradient_linear_sublane(
         out_specs=pl.BlockSpec((h, block_w), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((h, wid + pw), out_dtype),
         interpret=interpret,
+        name="gradient_linear",
     )(xp_min, xp_max)
     return out[:, :wid]

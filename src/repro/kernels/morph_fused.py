@@ -301,6 +301,7 @@ def morph2d_fused(
         out_specs=pl.BlockSpec((1, h, block_w), lambda bi, j: (bi, 0, j)),
         out_shape=jax.ShapeDtypeStruct((b, h, gw * block_w), x.dtype),
         interpret=interpret,
+        name=f"morph_fused_{mop.name}",
     )(*arrays)
     return out[:, :, :wid]
 
@@ -360,5 +361,6 @@ def gradient2d_fused(
         out_specs=pl.BlockSpec((1, h, block_w), lambda bi, j: (bi, 0, j)),
         out_shape=jax.ShapeDtypeStruct((b, h, gw * block_w), out_dtype),
         interpret=interpret,
+        name="morph_fused_gradient",
     )(*arrays_min, *arrays_max)
     return out[:, :, :wid]

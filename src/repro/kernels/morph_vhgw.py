@@ -98,5 +98,6 @@ def morph_vhgw_sublane(
         out_specs=pl.BlockSpec((h, block_w), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((h, wid + pw), x.dtype),
         interpret=interpret,
+        name=f"morph_vhgw_{mop.name}",
     )(xp)
     return out[:, :wid]

@@ -62,5 +62,6 @@ def transpose_tiled(
         out_specs=pl.BlockSpec((tile, tile), lambda i, j: (j, i)),
         out_shape=jax.ShapeDtypeStruct((w + pw, h + ph), x.dtype),
         interpret=interpret,
+        name="transpose_tiled",
     )(xp)
     return out[:w, :h]

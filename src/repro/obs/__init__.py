@@ -1,5 +1,5 @@
 """End-to-end serving observability (ISSUE 7): one metrics vocabulary,
-per-request tracing, and executor profiling for the morphology serving
+per-request tracing, and timed host stages for the morphology serving
 tier.
 
     from repro.obs import ObsConfig
@@ -16,12 +16,13 @@ Three layers (DESIGN.md §12):
   by-type merge semantics; the serving stats surfaces are views over one
   :class:`MetricsRegistry` per service, and the sharded router's stats are
   a :func:`merge_snapshots` over its shards.
-* ``trace`` — trace IDs minted at submit, spans across queue wait /
-  dispatch / executor / retry / bisection / failover hops, exported as
-  Chrome trace-event JSON.
+* ``trace`` — trace IDs minted at submit, spans across worker ingress /
+  queue wait / dispatch / retry / bisection / failover hops, each with
+  its parent span, exported as Chrome trace-event JSON.
 * ``runtime`` — :class:`ObsConfig` (off by default; ``None`` costs one
   ``is None`` check per hook site) and the :class:`Observability` object
-  holding the tracer + executor compile-vs-run profiling.
+  holding the tracer and the stages (timed sections of a span, mirrored
+  into the ``jax.profiler`` trace as ``morph_serve:<stage>``).
 """
 from repro.obs.metrics import (
     DEFAULT_LATENCY_BUCKETS_MS,
@@ -36,7 +37,6 @@ from repro.obs.metrics import (
     quantile_from_snapshot,
 )
 from repro.obs.runtime import (
-    EXECUTOR_BUCKETS_MS,
     Observability,
     ObsConfig,
     now_s,
@@ -52,7 +52,6 @@ from repro.obs.trace import (
 __all__ = [
     "DEFAULT_LATENCY_BUCKETS_MS",
     "POW2_BUCKETS",
-    "EXECUTOR_BUCKETS_MS",
     "Counter",
     "Gauge",
     "Histogram",

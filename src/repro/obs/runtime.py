@@ -2,21 +2,22 @@
 
 ``ObsConfig`` is the single gate (ISSUE 7): ``ServiceConfig.obs = None``
 (the default) means no ``Observability`` object is ever constructed and
-every hook site in the service/batcher/router is one ``is None`` check —
-the same zero-overhead-off contract as ``FaultInjector``. With a config
-present, the runtime owns:
+every hook site in the service/batcher/router/worker host is one ``is
+None`` check — the same zero-overhead-off contract as ``FaultInjector``.
+With a config present, the runtime owns:
 
 * a :class:`~repro.obs.trace.Tracer` (per-request spans, Chrome export);
-* executor profiling: compile-vs-run split per cache key (the first call
-  of a freshly built executor pays the XLA compile; later calls are pure
-  dispatch+run), recorded both as histograms in the service's metrics
-  registry and as a bounded per-key table;
+* *stages*: timed same-thread sections inside a span (``recv``, ``pad``,
+  ``launch``, ``d2h``, ``reply``, ``tile.*``), summed per name into the
+  span's ``stages`` arg in microseconds and, with ``jax_profiler`` on,
+  mirrored into the profiler's trace as ``morph_serve:<stage>``
+  annotations carrying the plan in their metadata, so a device profile
+  names what the host was doing in each idle gap. Stages never nest on a
+  thread and outer spans are not mirrored, so the innermost stage is the
+  only host annotation open at any instant;
 * ``BoundedIter`` iters-used/budget as first-class histograms (the
   counters in ``ServiceStats`` only give the mean; reconstruction-depth
-  *distribution* is what the wavefront ROADMAP item needs);
-* an opt-in ``jax.profiler`` annotation bracket around dispatches, so a
-  device profile collected with ``jax.profiler.trace`` carries the serving
-  plan names.
+  *distribution* is what the wavefront ROADMAP item needs).
 
 The metrics registry itself is NOT gated: it is the always-on substrate
 ``stats()`` is built from (plain int adds under existing locks — the
@@ -33,14 +34,6 @@ import time
 from repro.obs.metrics import MetricsRegistry, POW2_BUCKETS
 from repro.obs.trace import Tracer, chrome_trace, new_trace_id
 
-# Executor timings spread over ~5 orders of magnitude (sub-ms dispatch to
-# multi-second cold compiles); reuse the latency ladder's shape but extend
-# the top for compile outliers.
-EXECUTOR_BUCKETS_MS = (
-    0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0, 500.0,
-    1000.0, 2500.0, 5000.0, 10000.0, 30000.0, 60000.0, 120000.0,
-)
-
 
 @dataclasses.dataclass(frozen=True)
 class ObsConfig:
@@ -50,13 +43,11 @@ class ObsConfig:
 
     trace: bool = True            # per-request spans + Chrome export
     trace_ring: int = 8192        # finished spans kept per tracer
-    profile_executors: bool = True  # compile/run split + per-key table
-    profile_keys: int = 256       # bound on the per-key profile table
-    jax_profiler: bool = False    # jax.profiler.TraceAnnotation per dispatch
+    jax_profiler: bool = False    # stages as jax.profiler.TraceAnnotation
 
     @property
     def enabled(self) -> bool:
-        return self.trace or self.profile_executors or self.jax_profiler
+        return self.trace or self.jax_profiler
 
 
 class Observability:
@@ -73,28 +64,64 @@ class Observability:
             Tracer(ring=config.trace_ring, pid=pid, name=name)
             if config.trace else None
         )
-        self._plock = threading.Lock()
-        self._cold: set = set()
-        self._profile: dict[str, dict] = {}
-        if config.profile_executors:
-            self._h_first = registry.histogram(
-                "executor.first_call_ms", EXECUTOR_BUCKETS_MS)
-            self._h_run = registry.histogram(
-                "executor.run_ms", EXECUTOR_BUCKETS_MS)
-            self._h_iters_used = registry.histogram(
-                "bounded_iter.used", POW2_BUCKETS)
-            self._h_iters_budget = registry.histogram(
-                "bounded_iter.budget", POW2_BUCKETS)
+        self._annotation = None
+        if config.jax_profiler:
+            import jax.profiler
+
+            self._annotation = jax.profiler.TraceAnnotation
+        # the span whose stage this thread is in: a span begun there (the
+        # queue span of a request submitted during an ingress span's recv
+        # stage) takes it as parent
+        self._inside = threading.local()
+        self._h_iters_used = registry.histogram("bounded_iter.used", POW2_BUCKETS)
+        self._h_iters_budget = registry.histogram(
+            "bounded_iter.budget", POW2_BUCKETS)
 
     # -------------------------------------------------------- request spans
+    def begin(self, name: str, trace=None, **attrs):
+        """Open a cross-thread span (None when tracing is off); close it
+        with :meth:`end`."""
+        if self.tracer is None:
+            return None
+        return self.tracer.begin(name, trace=trace, **attrs)
+
+    def end(self, span, **attrs) -> None:
+        if span is not None:
+            self.tracer.end(span, **attrs)
+
+    @contextlib.contextmanager
+    def stage(self, span, name: str, plan: str | None = None):
+        """Time a same-thread section of ``span`` (which may be None):
+        its microseconds add to ``span.attrs["stages"][name]`` and, with
+        ``jax_profiler`` on, it is a ``morph_serve:<name>`` annotation in
+        the profiler's trace with ``plan`` in its metadata."""
+        ann = None
+        if self._annotation is not None:
+            ann = self._annotation(f"morph_serve:{name}", plan=plan)
+            ann.__enter__()
+        self._inside.span = span
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            dt = time.perf_counter() - t0
+            self._inside.span = None
+            if ann is not None:
+                ann.__exit__(None, None, None)
+            if span is not None:
+                stages = span.attrs.setdefault("stages", {})
+                stages[name] = stages.get(name, 0.0) + dt * 1e6
+
     def request_submitted(self, req, plan_name: str, bucket, dtype: str) -> None:
         """Mint the request's trace ID (unless a router hop already did) and
-        open its queue-wait span."""
+        open its queue-wait span, the child of the span whose stage this
+        thread is in (the worker host's ``ingress``), if any."""
         if req.trace is None:
             req.trace = new_trace_id()
         if self.tracer is not None:
             req.qspan = self.tracer.begin(
                 "queue", trace=req.trace,
+                parent=getattr(self._inside, "span", None),
                 plan=plan_name, bucket=bucket, dtype=dtype,
             )
 
@@ -128,90 +155,19 @@ class Observability:
             attrs["trace_ids"] = [r.trace for r in reqs]
         self.tracer.instant(name, **attrs)
 
-    # ----------------------------------------------------- executor profile
-    def executor_built(self, key) -> None:
-        """Called when the cache misses and a new executor is built: its
-        next call pays the XLA compile."""
-        if not self.config.profile_executors:
-            return
-        with self._plock:
-            self._cold.add(key)
-
-    def record_execution(self, key, plan_name: str, dur_s: float) -> bool:
-        """Record one executor call (dispatch + block-until-ready). Returns
-        whether this was the key's compiling first call."""
-        if not self.config.profile_executors:
-            return False
-        dur_ms = dur_s * 1e3
-        with self._plock:
-            cold = key in self._cold
-            self._cold.discard(key)
-            ks = _key_str(key)
-            row = self._profile.get(ks)
-            if row is None:
-                if len(self._profile) >= self.config.profile_keys:
-                    row = None  # table full: histograms still record
-                else:
-                    row = self._profile[ks] = {
-                        "plan": plan_name, "first_call_ms": None,
-                        "calls": 0, "run_ms_total": 0.0, "run_ms_max": 0.0,
-                    }
-            if row is not None:
-                if cold:
-                    row["first_call_ms"] = round(dur_ms, 3)
-                else:
-                    row["calls"] += 1
-                    row["run_ms_total"] += dur_ms
-                    row["run_ms_max"] = max(row["run_ms_max"], dur_ms)
-        (self._h_first if cold else self._h_run).observe(dur_ms)
-        return cold
-
     def record_bounded(self, used: int, budget: int) -> None:
-        if not self.config.profile_executors:
-            return
         self._h_iters_used.observe(used)
         self._h_iters_budget.observe(budget)
 
-    def dispatch_annotation(self, label: str):
-        """Opt-in jax.profiler bracket: names this dispatch in a device
-        profile collected around the serving process."""
-        if not self.config.jax_profiler:
-            return contextlib.nullcontext()
-        import jax.profiler
-
-        return jax.profiler.TraceAnnotation(f"morph_serve:{label}")
-
     # -------------------------------------------------------------- reading
-    def executor_profile(self) -> dict:
-        with self._plock:
-            return {
-                k: dict(
-                    v,
-                    run_ms_mean=(
-                        round(v["run_ms_total"] / v["calls"], 3)
-                        if v["calls"] else 0.0
-                    ),
-                )
-                for k, v in self._profile.items()
-            }
-
     def export_trace(self) -> dict:
         return chrome_trace([self.tracer])
 
     def snapshot(self) -> dict:
-        out = {
+        return {
             "trace": self.tracer.snapshot() if self.tracer is not None else None,
             "jax_profiler": self.config.jax_profiler,
         }
-        if self.config.profile_executors:
-            with self._plock:
-                out["profiled_keys"] = len(self._profile)
-        return out
-
-
-def _key_str(key) -> str:
-    # executor cache keys embed a Plan; render compactly and hashable-free
-    return "|".join(str(getattr(p, "name", p)) for p in key)
 
 
 def now_s() -> float:
@@ -221,4 +177,4 @@ def now_s() -> float:
     return time.perf_counter()
 
 
-__all__ = ["ObsConfig", "Observability", "EXECUTOR_BUCKETS_MS", "now_s"]
+__all__ = ["ObsConfig", "Observability", "now_s"]

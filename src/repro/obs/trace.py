@@ -2,10 +2,12 @@
 
 A *trace* is one request's journey: a trace ID is minted at ``submit()``
 (process-unique, so a request keeps its identity across shard failover
-hops) and every span recorded on its behalf carries it. Spans mark the
-pipeline stages — queue wait, group dispatch, executor, retry/backoff,
+hops) and every span recorded on its behalf carries it. Spans mark layer
+boundaries — worker ingress, queue wait, group dispatch, retry/backoff,
 bisection, router hops — with (plan, bucket, dtype, batch, shard) context
-in their args.
+in their args. Each span has a process-unique id and, where one caused it,
+the id of its ``parent`` (a worker's ``queue`` span is the child of its
+``ingress`` span); both export as args ``span_id`` and ``parent_id``.
 
 Spans cross threads (a queue span opens on the submitting thread and closes
 on the batcher worker), so the API is explicit ``begin()``/``end()`` handles
@@ -33,6 +35,7 @@ from contextlib import contextmanager
 
 _ids = itertools.count(1)
 _ids_lock = threading.Lock()
+_span_ids = itertools.count(1)  # next() on a count is atomic under the GIL
 
 
 def new_trace_id() -> int:
@@ -46,11 +49,13 @@ class Span:
     """An open span handle. Closed by ``Tracer.end`` (or the ``span()``
     context manager) exactly once."""
 
-    __slots__ = ("name", "trace", "t0", "t1", "tid", "attrs")
+    __slots__ = ("name", "trace", "id", "parent", "t0", "t1", "tid", "attrs")
 
-    def __init__(self, name: str, trace, tid: int, attrs: dict):
+    def __init__(self, name: str, trace, tid: int, attrs: dict, parent=None):
         self.name = name
         self.trace = trace
+        self.id = next(_span_ids)
+        self.parent = parent
         self.t0 = time.perf_counter()
         self.t1 = None
         self.tid = tid
@@ -73,8 +78,10 @@ class Tracer:
         self.spans_ended = 0
 
     # ------------------------------------------------------------- recording
-    def begin(self, name: str, trace=None, **attrs) -> Span:
-        span = Span(name, trace, threading.get_ident(), attrs)
+    def begin(self, name: str, trace=None, parent: Span | None = None,
+              **attrs) -> Span:
+        span = Span(name, trace, threading.get_ident(), attrs,
+                    parent.id if parent is not None else None)
         with self._lock:
             self._open.add(span)
             self.spans_begun += 1
@@ -147,6 +154,9 @@ class Tracer:
             args = {k: _jsonable(v) for k, v in s.attrs.items()}
             if s.trace is not None:
                 args["trace_id"] = s.trace
+            args["span_id"] = s.id
+            if s.parent is not None:
+                args["parent_id"] = s.parent
             ev = {
                 "name": s.name,
                 "cat": "serve",
@@ -169,6 +179,8 @@ def _jsonable(v):
         return v
     if isinstance(v, (tuple, list)):
         return [_jsonable(x) for x in v]
+    if isinstance(v, dict):
+        return {str(k): _jsonable(x) for k, x in v.items()}
     return str(v)
 
 

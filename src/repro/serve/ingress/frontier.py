@@ -30,7 +30,7 @@ level up, across worker **processes**:
   fleet-wide view; ``export_trace()`` stitches worker Chrome traces onto
   the frontier timeline using per-link clock offsets, so one trace ID
   minted here is followable from the frontier hop span into the owning
-  worker's queue/dispatch/executor spans.
+  worker's ingress/queue/dispatch spans.
 
 ``serve()`` wraps the frontier in a :class:`WorkerHost` — the frontier
 speaks the same protocol it consumes, so clients connect to one address
@@ -202,8 +202,8 @@ class Frontier:
             if _trace is not None:
                 trace = _trace
             else:
-                # minted HERE: the ID every hop span, worker queue span,
-                # and executor span carries — across process boundaries
+                # minted HERE: the ID every hop span, worker ingress span,
+                # and queue span carries — across process boundaries
                 trace = new_trace_id() if self._obs is not None else None
             outer: Future = Future()
             outer.add_done_callback(self._request_done)

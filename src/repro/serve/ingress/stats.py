@@ -16,7 +16,7 @@ offset per worker on its control-plane ping (NTP-style midpoint estimate,
 see ``Connection.ping``) and :func:`merge_process_traces` shifts each
 worker's event timestamps by it before merging — so a frontier-minted
 trace ID's spans line up on one timeline: ``hop`` on the frontier lane,
-queue/dispatch/executor spans on the worker lanes, microseconds apart the
+ingress/queue/dispatch spans on the worker lanes, microseconds apart the
 way they really were. Negative shifted timestamps clamp to zero (the
 Chrome trace format rejects negative ``ts``; sub-microsecond offset error
 near the epoch is noise, not signal).

@@ -30,6 +30,15 @@ with its result, late work with ``ServiceClosed``, and only a genuinely
 killed worker ever surfaces :class:`ConnectionLost`. ``kill()`` is that
 crash, for chaos tests: sockets drop with no drain and no typed goodbye.
 
+Where the wrapped service has observability on (its ``_obs``), each submit
+is an ``ingress`` span in the service's own tracer, carrying the request's
+trace ID from the header: it opens once the frame is decoded and closes
+once the reply bytes are sent, and times two stages, ``recv`` (decoding
+the tensor and plan, then ``submit_plan``, on the connection's reader
+thread) and ``reply`` (encoding the result and ``sendall``, on whichever
+thread resolves the future). A :class:`MorphService`'s ``queue`` span is
+its child (``Observability.request_submitted`` runs inside ``recv``).
+
 The module is also the subprocess entry point::
 
     python -m repro.serve.ingress.worker --config '{"max_batch": 16}'
@@ -41,6 +50,7 @@ from __future__ import annotations
 
 import argparse
 import collections
+import contextlib
 import glob
 import json
 import os
@@ -57,6 +67,7 @@ from repro.serve.morph.service import MorphService, ServiceConfig
 from repro.serve.morph.tenancy import PRIORITY_NORMAL, TenantQuota
 
 READY_SENTINEL = "INGRESS_WORKER_READY"
+_NULL = contextlib.nullcontext()
 
 
 def _open_spans(service) -> int:
@@ -91,6 +102,8 @@ class WorkerHost:
             config or ServiceConfig()
         )
         self.worker_id = worker_id
+        # the wrapped service's observability runtime (None when off)
+        self._obs = getattr(self.service, "_obs", None)
         self._lock = threading.Lock()
         self._drained = threading.Condition(self._lock)
         self._closing = False
@@ -215,38 +228,53 @@ class WorkerHost:
             self._outstanding += 1
             self.requests += 1
 
-        def finish_with(header2: dict, payload2: bytes = b"") -> None:
+        obs = self._obs
+        span = None
+        plan_name = None
+        if obs is not None:
+            span = obs.begin("ingress", trace=header.get("trace"),
+                             worker=self.worker_id)
+            plan_name = (header.get("plan") or {}).get("name")
+
+        def stage(name: str):
+            return obs.stage(span, name, plan_name) if obs is not None else _NULL
+
+        def reply(message) -> None:
             # the response is written BEFORE the outstanding count drops:
             # close() waiting on zero therefore waits for the bytes, which
             # is what "every client future resolves" means on the wire
             try:
-                send(header2, payload2)
+                with stage("reply"):
+                    send(*message())
             finally:
+                if span is not None:
+                    obs.end(span)
                 with self._lock:
                     self._outstanding -= 1
                     self._drained.notify_all()
 
         try:
-            plan = proto.plan_from_wire(header.get("plan") or {})
-            img = proto.decode_tensor(header.get("tensor") or {}, payload)
-            fut = self.service.submit_plan(
-                img, plan,
-                deadline_ms=header.get("deadline_ms"),
-                tag=header.get("tag"),
-                tenant=header.get("tenant"),
-                priority=header.get("priority", PRIORITY_NORMAL),
-                _trace=header.get("trace"),
-            )
+            with stage("recv"):
+                plan = proto.plan_from_wire(header.get("plan") or {})
+                img = proto.decode_tensor(header.get("tensor") or {}, payload)
+                fut = self.service.submit_plan(
+                    img, plan,
+                    deadline_ms=header.get("deadline_ms"),
+                    tag=header.get("tag"),
+                    tenant=header.get("tenant"),
+                    priority=header.get("priority", PRIORITY_NORMAL),
+                    _trace=header.get("trace"),
+                )
         except BaseException as exc:  # noqa: BLE001 — typed over the wire
-            finish_with(proto.error_message(rid, exc)[0])
+            reply(lambda: proto.error_message(rid, exc))
             return
 
         def done(f) -> None:
             exc = f.exception()
             if exc is None:
-                finish_with(*proto.result_message(rid, f.result()))
+                reply(lambda: proto.result_message(rid, f.result()))
             else:
-                finish_with(proto.error_message(rid, exc)[0])
+                reply(lambda: proto.error_message(rid, exc))
 
         fut.add_done_callback(done)
 

@@ -21,10 +21,16 @@ is a view over one :class:`~repro.obs.MetricsRegistry` per service —
 ``stats()`` derives its dict from registry metrics, and the sharded router
 merges registries by metric type instead of re-aggregating stats dicts.
 Passing ``ServiceConfig(obs=ObsConfig())`` additionally turns on
-per-request tracing (trace ID minted at submit, spans over queue wait /
-dispatch / executor, exported via :meth:`MorphService.export_trace` as
-Chrome trace-event JSON) and executor profiling (compile-vs-run split per
-cache key); ``obs=None`` (default) costs one ``is None`` check per hook.
+per-request tracing (trace ID minted at submit, spans over queue wait and
+dispatch, exported via :meth:`MorphService.export_trace` as Chrome
+trace-event JSON) and the dispatch span's timed stages (``pad``,
+``launch``, ``d2h``; ``tile.gather``, ``tile.launch``, ``tile.stitch`` on
+the tiled route); ``obs=None`` (default) costs one ``is None`` check per
+hook. Every launch, traced or not, adds the pixels it answered and the
+pixels it launched (batch slots times bucket or tile extent) to the
+``executor.pixels_valid`` / ``executor.pixels_launched`` counters, and a
+tiled launch its real tiles to ``tiled.tiles`` and one to
+``tiled.launches``.
 """
 from __future__ import annotations
 
@@ -85,6 +91,8 @@ from repro.serve.morph.plans import (
 )
 from repro.serve.morph.tiling import run_tiled
 
+
+_NULL = contextlib.nullcontext()
 
 # Run-density histogram bounds (runs per pixel): log-spaced over the range
 # the representation gate discriminates on — 0.1% (deep-RLE territory)
@@ -330,8 +338,8 @@ class ServiceConfig:
     failover: FailoverPolicy = FailoverPolicy()
     # Deterministic fault injection; None (default) adds zero overhead.
     faults: FaultPlan | None = None
-    # Observability (repro.obs): tracing + executor profiling; None
-    # (default) adds zero overhead, same contract as ``faults``.
+    # Observability (repro.obs): spans and stages; None (default) adds
+    # zero overhead, same contract as ``faults``.
     obs: ObsConfig | None = None
 
 
@@ -380,12 +388,22 @@ class MorphService:
         self._rle_eligible: dict = {}
         self._rle_exec: dict = {}
         self._stats = ServiceStats(self.config.stats_window, registry=self.metrics)
+        # launch occupancy: pixels answered against pixels launched, and
+        # tiles per launch on the tiled route (the dispatch thread is their
+        # only writer)
+        self._px_valid = self.metrics.counter("executor.pixels_valid")
+        self._px_launched = self.metrics.counter("executor.pixels_launched")
+        self._tiles = self.metrics.counter("tiled.tiles")
+        self._tile_launches = self.metrics.counter("tiled.launches")
         faults = self.config.faults
         self._injector = (
             FaultInjector(faults) if faults is not None and faults.enabled else None
         )
         obs_cfg = self.config.obs
         shard = self.config.shard
+        # the open dispatch span, while the batcher's one dispatch thread
+        # executes a group (None when tracing is off)
+        self._dspan = None
         self._obs = (
             Observability(
                 obs_cfg,
@@ -566,7 +584,6 @@ class MorphService:
         return True
 
     def _execute_rle(self, reqs: list) -> None:
-        obs = self._obs
         for r in reqs:
             if r.future.done():
                 continue  # already served before a batch-mate failed a retry
@@ -574,12 +591,8 @@ class MorphService:
                 continue
             if self._injector is not None:
                 self._injector.before_dispatch([r])
-            span = (obs.group_span("executor", [r], plan=r.plan.name,
-                                   kind="rle", shard=self.config.shard)
-                    if obs is not None else contextlib.nullcontext())
             try:
-                with span:
-                    outs = self._rle_executor(r.plan)(r.img)
+                outs = self._rle_executor(r.plan)(r.img)
             except ServeError:
                 raise
             except Exception as exc:
@@ -612,10 +625,6 @@ class MorphService:
         key = self._executor_key(plan, shape, dtype, batch)
 
         def build():
-            if self._obs is not None:
-                # the key's next call pays the XLA compile (profiled as the
-                # compile-vs-run split)
-                self._obs.executor_built(key)
             return build_executor(
                 plan,
                 backend=self.backend,
@@ -641,14 +650,23 @@ class MorphService:
                 shard=self.config.shard,
             )
         else:
-            span = contextlib.nullcontext()
-        with span, self._device_scope():
-            if key[0] == "tiled":
-                self._execute_tiled(reqs)
-            elif key[0] == "rle":
-                self._execute_rle(reqs)
-            else:
-                self._execute_bucketed(key, reqs)
+            span = _NULL
+        with span as self._dspan, self._device_scope():
+            try:
+                if key[0] == "tiled":
+                    self._execute_tiled(reqs)
+                elif key[0] == "rle":
+                    self._execute_rle(reqs)
+                else:
+                    self._execute_bucketed(key, reqs)
+            finally:
+                self._dspan = None
+
+    def _stage(self, name: str, plan: str):
+        """A timed stage of the open dispatch span (see
+        ``Observability.stage``); nothing when obs is off."""
+        obs = self._obs
+        return obs.stage(self._dspan, name, plan) if obs is not None else _NULL
 
     def _count_device(self, outs: dict) -> None:
         """Count the dispatch under the device its outputs landed on
@@ -666,39 +684,30 @@ class MorphService:
 
     def _execute_bucketed(self, key, reqs: list) -> None:
         _, plan, bucket, _ = key
-        obs = self._obs
         if self._injector is not None:
             self._injector.before_dispatch(reqs)
         bb = min(_round_up_pow2(len(reqs)), self.config.max_batch)
-        batch = np.zeros((bb, *bucket), dtype=reqs[0].img.dtype)
-        rects = np.zeros((bb, 4), dtype=np.int32)
-        for i, r in enumerate(reqs):
-            h, w = r.img.shape
-            batch[i, :h, :w] = r.img  # rows past len(reqs) keep an empty rect
-            rects[i] = valid_rect(h, w)
+        dtype = reqs[0].img.dtype
+        if self._dspan is not None:
+            self._dspan.attrs.update(bucket=bucket, dtype=np.dtype(dtype).name,
+                                     batch=bb)
+        valid = 0
+        with self._stage("pad", plan.name):
+            batch = np.zeros((bb, *bucket), dtype=dtype)
+            rects = np.zeros((bb, 4), dtype=np.int32)
+            for i, r in enumerate(reqs):
+                h, w = r.img.shape
+                batch[i, :h, :w] = r.img  # rows past len(reqs) keep an empty rect
+                rects[i] = valid_rect(h, w)
+                valid += h * w
         try:
-            execute = self._executor_for(plan, bucket, batch.dtype, bb)
-            if obs is not None:
-                span = obs.group_span(
-                    "executor", reqs, plan=plan.name, bucket=bucket,
-                    dtype=np.dtype(batch.dtype).name, batch=bb,
-                    shard=self.config.shard,
-                )
-                t0 = time.perf_counter()
-            else:
-                span = contextlib.nullcontext()
-            with span, (obs.dispatch_annotation(plan.name) if obs is not None
-                        else contextlib.nullcontext()):
+            with self._stage("launch", plan.name):
+                execute = self._executor_for(plan, bucket, dtype, bb)
                 outs, aux = execute(jnp.asarray(batch), jnp.asarray(rects))
                 self._count_device(outs)
-                # np.asarray blocks until ready: the executor span covers
-                # dispatch + device run, not just the enqueue
+            with self._stage("d2h", plan.name):
+                # np.asarray blocks until the device is done
                 outs = {k: np.asarray(v) for k, v in outs.items()}
-            if obs is not None:
-                obs.record_execution(
-                    self._executor_key(plan, bucket, batch.dtype, bb),
-                    plan.name, time.perf_counter() - t0,
-                )
         except ServeError:
             raise
         except Exception as exc:
@@ -706,9 +715,11 @@ class MorphService:
                 f"executor failed: {type(exc).__name__}: {exc}",
                 plan=plan.name,
                 bucket=bucket,
-                dtype=np.dtype(batch.dtype).name,
+                dtype=np.dtype(dtype).name,
                 batch=bb,
             ) from exc
+        self._px_valid.inc(valid)
+        self._px_launched.inc(bb * bucket[0] * bucket[1])
         self._record_aux(aux)
         names = plan.output_names()
         # record stats before resolving futures: a caller returning from
@@ -725,8 +736,13 @@ class MorphService:
                     cropped["out"] if names == ("out",) else cropped
                 )
 
+    def _count_tiled_launch(self, tiles: int, valid: int, launched: int) -> None:
+        self._tiles.inc(tiles)
+        self._tile_launches.inc()
+        self._px_valid.inc(valid)
+        self._px_launched.inc(launched)
+
     def _execute_tiled(self, reqs: list) -> None:
-        obs = self._obs
         for r in reqs:
             if r.future.done():
                 continue  # already served before a batch-mate failed a retry
@@ -737,6 +753,9 @@ class MorphService:
             gh, gw = r.plan.halo()
             ext = (self.config.tile_interior[0] + 2 * gh,
                    self.config.tile_interior[1] + 2 * gw)
+            if self._dspan is not None:
+                self._dspan.attrs.update(bucket=ext,
+                                         dtype=np.dtype(r.img.dtype).name)
 
             aux_chunks: list = []
 
@@ -747,20 +766,16 @@ class MorphService:
                 aux_chunks.append(aux)  # record after all chunks dispatch:
                 return outs             # int(aux) here would sync per launch
 
-            span = (obs.group_span("executor", [r], plan=r.plan.name,
-                                   bucket=ext, kind="tiled",
-                                   shard=self.config.shard)
-                    if obs is not None else contextlib.nullcontext())
             try:
-                with span, (obs.dispatch_annotation(r.plan.name)
-                            if obs is not None else contextlib.nullcontext()):
-                    outs = run_tiled(
-                        r.img,
-                        r.plan,
-                        execute,
-                        tile_interior=self.config.tile_interior,
-                        launch_batch=self.config.max_tiles_per_launch,
-                    )
+                outs = run_tiled(
+                    r.img,
+                    r.plan,
+                    execute,
+                    tile_interior=self.config.tile_interior,
+                    launch_batch=self.config.max_tiles_per_launch,
+                    stage=lambda name: self._stage(name, r.plan.name),
+                    on_launch=self._count_tiled_launch,
+                )
             except ServeError:
                 raise
             except Exception as exc:
@@ -805,11 +820,6 @@ class MorphService:
         snap["resilience"] = resilience
         snap["obs"] = self._obs.snapshot() if self._obs is not None else None
         return snap
-
-    def executor_profile(self) -> dict:
-        """Per-cache-key compile/run profile (empty unless ``obs`` enables
-        executor profiling)."""
-        return self._obs.executor_profile() if self._obs is not None else {}
 
     def export_trace(self) -> dict | None:
         """Chrome trace-event JSON of the finished spans (Perfetto-loadable);

@@ -31,6 +31,7 @@ executable per (plan, tile shape, dtype) exactly like bucketed traffic.
 """
 from __future__ import annotations
 
+import contextlib
 import math
 
 import jax.numpy as jnp
@@ -109,6 +110,8 @@ def run_tiled(
     *,
     tile_interior: tuple[int, int],
     launch_batch: int,
+    stage=None,
+    on_launch=None,
 ) -> dict[str, np.ndarray]:
     """Execute ``plan`` over ``img`` in halo tiles and stitch the interiors.
 
@@ -119,40 +122,59 @@ def run_tiled(
     image size instead of one compile per distinct tile count. Tiles arrive
     as device arrays and interiors stitch on device; each named output
     crosses to the host exactly once.
+
+    ``stage(name)``, where given, returns a context manager that times the
+    sections ``tile.gather``, ``tile.launch`` and ``tile.stitch``;
+    ``on_launch(tiles, valid_px, launched_px)`` is called once per launch
+    with its real tiles, the image pixels they own and the pixels launched.
     """
+    if stage is None:
+        stage = _no_stage
     gh, gw = plan.halo()
-    tiles, rects, interiors = extract_tiles(img, plan, tile_interior)
+    with stage("tile.gather"):
+        tiles, rects, interiors = extract_tiles(img, plan, tile_interior)
     n = int(tiles.shape[0])
     h, w = img.shape
     ny, nx = tile_counts(h, w, tile_interior)
     launch_batch = max(1, min(launch_batch, 1 << (n - 1).bit_length() if n else 1))
     crops: dict[str, list] = {}
     for i0 in range(0, n, launch_batch):
-        chunk = tiles[i0 : i0 + launch_batch]
-        crect = rects[i0 : i0 + launch_batch]
-        pad = launch_batch - int(chunk.shape[0])
-        if pad:
-            chunk = jnp.concatenate(
-                [chunk, jnp.zeros((pad, *chunk.shape[1:]), chunk.dtype)]
-            )
-            crect = np.concatenate([crect, np.zeros((pad, 4), np.int32)])
-        res = execute(chunk, crect)
-        for name, val in res.items():
-            slots = crops.setdefault(name, [None] * n)
-            for j in range(min(launch_batch, n - i0)):
-                _, _, ih, iw = interiors[i0 + j]
-                slots[i0 + j] = lax.slice(val[j], (gh, gw), (gh + ih, gw + iw))
+        real = min(launch_batch, n - i0)
+        with stage("tile.launch"):
+            chunk = tiles[i0 : i0 + launch_batch]
+            crect = rects[i0 : i0 + launch_batch]
+            pad = launch_batch - real
+            if pad:
+                chunk = jnp.concatenate(
+                    [chunk, jnp.zeros((pad, *chunk.shape[1:]), chunk.dtype)]
+                )
+                crect = np.concatenate([crect, np.zeros((pad, 4), np.int32)])
+            res = execute(chunk, crect)
+        if on_launch is not None:
+            on_launch(real, sum(ih * iw for _, _, ih, iw in interiors[i0 : i0 + real]),
+                      int(chunk.size))
+        with stage("tile.stitch"):
+            for name, val in res.items():
+                slots = crops.setdefault(name, [None] * n)
+                for j in range(real):
+                    _, _, ih, iw = interiors[i0 + j]
+                    slots[i0 + j] = lax.slice(val[j], (gh, gw), (gh + ih, gw + iw))
     # Stitch by row-wise concatenation — O(H*W) total, vs a full-image copy
     # per tile that eager dynamic_update_slice would cost — still device-
     # side; each named output crosses to the host exactly once.
     outs: dict[str, np.ndarray] = {}
-    for name, slots in crops.items():
-        rows = [
-            jnp.concatenate(slots[r * nx : (r + 1) * nx], axis=1)
-            if nx > 1 else slots[r * nx]
-            for r in range(ny)
-        ]
-        outs[name] = np.asarray(
-            jnp.concatenate(rows, axis=0) if ny > 1 else rows[0]
-        )
+    with stage("tile.stitch"):
+        for name, slots in crops.items():
+            rows = [
+                jnp.concatenate(slots[r * nx : (r + 1) * nx], axis=1)
+                if nx > 1 else slots[r * nx]
+                for r in range(ny)
+            ]
+            outs[name] = np.asarray(
+                jnp.concatenate(rows, axis=0) if ny > 1 else rows[0]
+            )
     return outs
+
+
+def _no_stage(name: str):
+    return contextlib.nullcontext()

@@ -65,7 +65,7 @@ merged p50/p99 are true cross-shard quantiles — and the full per-shard list
 rides along. With ``ServiceConfig.obs`` set, the router also traces: one
 trace ID is minted per request and threaded through every failover hop
 (each hop is a span on the router's ``"router"`` lane; shard-side queue/
-dispatch/executor/retry spans carry the same ID), and ``export_trace()``
+dispatch/retry spans carry the same ID), and ``export_trace()``
 merges the router and all shard tracers onto one Chrome-trace timeline.
 """
 from __future__ import annotations

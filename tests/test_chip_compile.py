@@ -54,10 +54,13 @@ def one_chip():
             os.environ["TPU_LOG_DIR"] = prev_log
 
 
-def compile_for_chip(fn, sharding, shape, dtype):
+def compile_for_chip(fn, sharding, shape, dtype, kernels=()):
     x = jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
     compiled = jax.jit(fn).lower(x).compile()
-    assert "tpu_custom_call" in compiled.as_text()  # the Pallas kernel is there
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text  # the Pallas kernel is there
+    for name in kernels:  # under its stable name, which the trace shows
+        assert f"%{name}" in text, name
     return compiled
 
 
@@ -70,14 +73,14 @@ def compile_for_chip(fn, sharding, shape, dtype):
 def test_fused_erode_compiles(one_chip, shape, dtype):
     compile_for_chip(
         lambda x: raw_morph2d(x, (15, 15), "min", policy=FUSED, interpret=False),
-        one_chip, shape, dtype,
+        one_chip, shape, dtype, kernels=("morph_fused_min",),
     )
 
 
 def test_fused_gradient_u8_compiles(one_chip):
     compile_for_chip(
         lambda x: gradient2d_fused(x, (5, 5), policy=FUSED, interpret=False),
-        one_chip, PAPER, jnp.uint8,
+        one_chip, PAPER, jnp.uint8, kernels=("morph_fused_gradient",),
     )
 
 
@@ -85,11 +88,12 @@ def test_two_pass_u8_compiles(one_chip):
     # H pass, then transpose -> W pass -> transpose: four kernels
     compile_for_chip(
         lambda x: raw_morph2d(x, (15, 15), "min", policy=TWO_PASS, interpret=False),
-        one_chip, PAPER[1:], jnp.uint8,
+        one_chip, PAPER[1:], jnp.uint8, kernels=("morph_linear_min", "transpose_tiled"),
     )
 
 
 def test_transpose_tiled_u8_compiles(one_chip):
     compile_for_chip(
-        lambda x: transpose_tiled(x, interpret=False), one_chip, PAPER[1:], jnp.uint8
+        lambda x: transpose_tiled(x, interpret=False), one_chip, PAPER[1:], jnp.uint8,
+        kernels=("transpose_tiled",),
     )

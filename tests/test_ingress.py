@@ -34,6 +34,7 @@ import zlib
 import numpy as np
 import pytest
 
+from repro.obs import ObsConfig
 from repro.serve.ingress import proto
 from repro.serve.ingress.client import Connection, IngressClient
 from repro.serve.ingress.frontier import Frontier
@@ -317,6 +318,91 @@ def test_worker_serves_bit_exact_results():
     assert stats["requests"] >= len(imgs)
     assert health["worker"] == 0 and health["closing"] is False
     assert host.requests == len(imgs)
+
+
+# ==================================================== worker: ingress spans
+STAGES = {"recv", "reply", "pad", "launch", "d2h",
+          "tile.gather", "tile.launch", "tile.stitch"}
+
+
+def test_worker_host_holds_no_tracer_with_obs_off():
+    with WorkerHost(config=svc_cfg()) as host:
+        assert host._obs is None
+        with IngressClient(host.address) as client:
+            client.run(rand(), "erode", (3, 3))
+    svc = MorphService(svc_cfg(obs=ObsConfig()))
+    with WorkerHost(svc) as host:
+        assert host._obs is svc._obs  # records into the service's own tracer
+
+
+def test_ingress_spans_three_per_request_at_batch_one():
+    """The ring budget: one ``ingress``, one ``queue`` and one ``dispatch``
+    span per request at batch 1, stages as args; ``queue`` is the child of
+    ``ingress`` and lies inside it."""
+    n = 6
+    svc = MorphService(svc_cfg(obs=ObsConfig()))
+    with WorkerHost(svc) as host:
+        with IngressClient(host.address) as client:
+            for i in range(n):
+                client.run_plan(rand(), ERODE3, trace=1000 + i)
+    tracer = svc._obs.tracer
+    assert tracer.open_count() == 0
+    assert poll_until(lambda: all(
+        "recv" in s.attrs.get("stages", {})
+        for s in tracer.finished() if s.name == "ingress"))
+    spans = tracer.finished()
+    assert len(spans) == 3 * n
+    ingress = {s.trace: s for s in spans if s.name == "ingress"}
+    queue = {s.trace: s for s in spans if s.name == "queue"}
+    dispatch = [s for s in spans if s.name == "dispatch"]
+    traces = list(range(1000, 1000 + n))
+    assert sorted(ingress) == sorted(queue) == traces
+    assert sorted(t for d in dispatch for t in d.attrs["trace_ids"]) == traces
+    assert all(d.attrs["batch"] == 1 for d in dispatch)
+    for t, q in queue.items():
+        ing = ingress[t]
+        assert q.parent == ing.id and ing.parent is None
+        assert ing.t0 <= q.t0 <= q.t1 <= ing.t1
+        assert set(ing.attrs["stages"]) == {"recv", "reply"}
+
+
+def test_stages_in_the_profiler_trace(tmp_path):
+    """Under ``jax.profiler`` every stage is a ``morph_serve:<stage>`` host
+    event with the plan in its stats, stages never overlap on a thread, and
+    no event is named after a plan."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    svc = MorphService(svc_cfg(obs=ObsConfig(jax_profiler=True),
+                               tile_interior=(32, 32), max_tiles_per_launch=4))
+    small, big = rand(), rand(100, 90)  # bucketed, tiled (12 tiles)
+    with WorkerHost(svc) as host, IngressClient(host.address) as client:
+        for img in (small, big):  # compile outside the trace
+            client.run_plan(img, ERODE3)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            for img in (small, big):
+                client.run_plan(img, ERODE3)
+        finally:
+            jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    seen = set()
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            evs = sorted(
+                ((ev.start_ns, ev.start_ns + ev.duration_ns, ev.name, dict(ev.stats))
+                 for ev in line.events if ev.name.startswith("morph_serve:")),
+                key=lambda e: e[0])
+            for (_, end, _, _), (start, _, _, _) in zip(evs, evs[1:]):
+                assert end <= start  # no two stages overlap on one thread
+            for _, _, name, stats in evs:
+                stage = name.split(":", 1)[1]
+                assert stage in STAGES, name  # never morph_serve:<plan>
+                assert stats.get("plan") == ERODE3.name
+                seen.add(stage)
+    assert seen == STAGES
 
 
 def test_worker_reconstructs_typed_errors():

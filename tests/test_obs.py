@@ -10,8 +10,10 @@ Three layers of guarantees:
 * **Tracing** — span handles close exactly once (double-end raises), the
   export is schema-valid Chrome trace-event JSON, and a chaos replay of the
   ISSUE 6 fault scenarios (failing shard + poison request) produces a trace
-  containing the full resilience vocabulary — queue, dispatch, executor,
-  retry, bisect, hop, failover — with zero spans left open.
+  containing the full resilience vocabulary — queue, dispatch, retry,
+  bisect, hop, failover — with zero spans left open; dispatch spans carry
+  their timed stages, and every launch counts the pixels it answered
+  against the pixels it launched.
 * **Gating** — ``obs=None`` (the default) constructs no observability
   runtime at all: the off path is structurally the pre-obs service.
 
@@ -46,6 +48,7 @@ from repro.serve.morph import (
     RetryPolicy,
     ServeError,
     ServiceConfig,
+    get_plan,
     single_op_plan,
 )
 from repro.shard import ShardedMorphService
@@ -322,7 +325,11 @@ def test_obs_off_is_structurally_absent():
         assert svc._obs is None
         assert svc._batcher._obs is None
         assert svc.export_trace() is None
-        assert svc.executor_profile() == {}
+        assert svc.stats()["obs"] is None
+        # obs gates spans and stages only: the launch counters stay on
+        snap = svc.metrics_snapshot()
+        assert snap["executor.pixels_valid"]["value"] == 40 * 50
+        assert not any(k.startswith("bounded_iter.used") for k in snap)
     devices = [jax.devices()[0]] * 2
     with ShardedMorphService(cfg(), devices=devices) as svc:
         assert svc._obs is None
@@ -331,9 +338,14 @@ def test_obs_off_is_structurally_absent():
 
 def test_obs_config_enabled_flag():
     assert ObsConfig().enabled
-    assert not ObsConfig(trace=False, profile_executors=False).enabled
-    assert ObsConfig(trace=False, profile_executors=False,
-                     jax_profiler=True).enabled
+    assert not ObsConfig(trace=False).enabled
+    assert ObsConfig(trace=False, jax_profiler=True).enabled
+    with MorphService(cfg(obs=ObsConfig(trace=False))) as svc:
+        assert svc._obs is None
+    # stages without spans: the profiler annotations alone
+    with MorphService(cfg(obs=ObsConfig(trace=False, jax_profiler=True))) as svc:
+        svc.run(rand(), "erode", (3, 3))
+        assert svc._obs.tracer is None and svc.export_trace() is None
 
 
 # -------------------------------------------------------- enabled pipeline
@@ -343,26 +355,76 @@ def test_single_service_trace_and_profile():
             svc.run(rand(), "erode", (3, 3))
         svc.flush(10)
         st = svc.stats()
-        prof = svc.executor_profile()
         doc = svc.export_trace()
         assert svc._obs.tracer.open_count() == 0
     assert validate_chrome_trace(doc) == []
     names = {e["name"] for e in doc["traceEvents"]}
-    assert {"queue", "dispatch", "executor"} <= names
+    assert {"queue", "dispatch"} <= names and "executor" not in names
     # every request minted a distinct trace id, carried by its queue span
     qids = [
         e["args"]["trace_id"] for e in doc["traceEvents"]
         if e["name"] == "queue"
     ]
     assert len(qids) == 4 and len(set(qids)) == 4
-    # compile-vs-run split: one cold first call, three warm runs
-    assert len(prof) == 1
-    row = next(iter(prof.values()))
-    assert row["first_call_ms"] is not None
-    assert row["calls"] == 3
-    assert row["first_call_ms"] > row["run_ms_mean"]
+    # the dispatch span carries the launch's args and its stage split, the
+    # durations the per-key executor profile used to time a second time
+    dispatches = [e for e in doc["traceEvents"] if e["name"] == "dispatch"]
+    assert len(dispatches) == 4
+    for ev in dispatches:
+        args = ev["args"]
+        assert args["plan"] == "erode" and args["bucket"] == [64, 64]
+        assert args["dtype"] == "uint8" and args["batch"] == 1
+        assert set(args["stages"]) == {"pad", "launch", "d2h"}
+        assert 0 < sum(args["stages"].values()) <= ev["dur"]
+    # the cold first launch paid the compile: its launch stage is the longest
+    launches = [e["args"]["stages"]["launch"] for e in dispatches]
+    assert launches[0] == max(launches)
     assert st["obs"]["trace"]["open"] == 0
-    assert st["obs"]["profiled_keys"] == 1
+    assert "profiled_keys" not in st["obs"]
+
+
+def test_span_ids_parents_and_stages_export():
+    t = Tracer()
+    outer = t.begin("ingress", trace=7)
+    inner = t.begin("queue", trace=7, parent=outer)
+    outer.attrs["stages"] = {"recv": 12.5}
+    t.end(inner)
+    t.end(outer)
+    evs = {e["name"]: e for e in t.chrome_events() if e["ph"] == "X"}
+    assert evs["queue"]["args"]["parent_id"] == evs["ingress"]["args"]["span_id"]
+    assert "parent_id" not in evs["ingress"]["args"]
+    assert evs["ingress"]["args"]["stages"] == {"recv": 12.5}
+    assert inner.id != outer.id
+
+
+FRAME = (600, 800)
+PAGE = (3508, 2480)  # A4 at 300 dpi, portrait
+
+
+@pytest.mark.parametrize("shape,n,plan", [
+    (FRAME, 5, single_op_plan("erode", (3, 3))),  # one (608, 896) batch of 8 slots
+    (PAGE, 1, "document_cleanup"),  # 35 tiles in launches of 16, 16 and 3 + 13 dummies
+], ids=["frames_batch_of_5", "a4_page"])
+def test_launch_counters_are_exact(shape, n, plan):
+    """Pixels answered against pixels launched (slots times bucket or tile
+    extent), and real tiles per launch, counted with obs off."""
+    c = ServiceConfig(window_ms=2000.0, adaptive_window=False)
+    with MorphService(c) as svc:
+        futs = [svc.submit_plan(rand(*shape), plan) for _ in range(n)]
+        for f in futs:
+            f.result(timeout=300)
+        snap = {k: v["value"] for k, v in svc.metrics_snapshot().items()
+                if v["type"] == "counter"}
+    if shape == FRAME:
+        assert snap["batches"] == 1
+        launched, tiles, launches = 8 * 608 * 896, 0, 0
+    else:
+        gh, gw = get_plan("document_cleanup").halo()
+        launched, tiles, launches = 3 * 16 * (512 + 2 * gh) * (512 + 2 * gw), 35, 3
+    assert snap["executor.pixels_valid"] == n * shape[0] * shape[1]
+    assert snap["executor.pixels_launched"] == launched
+    assert snap["tiled.tiles"] == tiles
+    assert snap["tiled.launches"] == launches
 
 
 def test_submit_rejection_leaves_no_open_spans():
@@ -442,7 +504,7 @@ def test_chaos_trace_is_complete():
     assert outcomes[3] == "poison"
     assert validate_chrome_trace(doc) == []
     names = {e["name"] for e in doc["traceEvents"]}
-    assert {"queue", "dispatch", "executor", "retry", "bisect", "hop",
+    assert {"queue", "dispatch", "retry", "bisect", "hop",
             "failover"} <= names, names
     # the failing primary tripped its breaker and traffic moved
     assert stats["resilience"]["failovers"] >= 1
