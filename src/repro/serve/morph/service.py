@@ -10,11 +10,13 @@ morphology requests into (B, H, W) stacks. A request flows:
       -> execute (plans.py executor from the LRU executable cache)
       -> crop + resolve the Future
 
-Images too large for the ladder take the tiled route (tiling.py) through
-the same executor cache. The executable cache is keyed on
-``(plan, shape, dtype, batch-bucket, policy.cache_token(), backend,
-interpret)`` with hit/miss/eviction counters; batch sizes are bucketed to
-powers of two so B-variance cannot silently multiply compiles.
+Images too large for the ladder take the tiled route (tiling.py): one
+program per tile grid, gather, passes and stitch on device, from the same
+executable cache. The cache is keyed on ``(plan, shape, dtype,
+batch-bucket, policy.cache_token(), backend, interpret)`` for buckets and
+on the grid ``(ny, nx)`` and tile extent for pages, with hit/miss/eviction
+counters; batch sizes are bucketed to powers of two so B-variance cannot
+silently multiply compiles.
 
 Observability (ISSUE 7, ``repro.obs``): every counter/latency surface here
 is a view over one :class:`~repro.obs.MetricsRegistry` per service —
@@ -29,8 +31,8 @@ the tiled route); ``obs=None`` (default) costs one ``is None`` check per
 hook. Every launch, traced or not, adds the pixels it answered and the
 pixels it launched (batch slots times bucket or tile extent) to the
 ``executor.pixels_valid`` / ``executor.pixels_launched`` counters, and a
-tiled launch its real tiles to ``tiled.tiles`` and one to
-``tiled.launches``.
+tiled page's program call its ``ny*nx`` tiles to ``tiled.tiles`` and one
+to ``tiled.launches``.
 """
 from __future__ import annotations
 
@@ -89,7 +91,7 @@ from repro.serve.morph.plans import (
     get_plan,
     single_op_plan,
 )
-from repro.serve.morph.tiling import run_tiled
+from repro.serve.morph.tiling import build_grid_executor, run_grid, tile_counts
 
 
 _NULL = contextlib.nullcontext()
@@ -736,11 +738,30 @@ class MorphService:
                     cropped["out"] if names == ("out",) else cropped
                 )
 
-    def _count_tiled_launch(self, tiles: int, valid: int, launched: int) -> None:
-        self._tiles.inc(tiles)
-        self._tile_launches.inc()
-        self._px_valid.inc(valid)
-        self._px_launched.inc(launched)
+    def _grid_executor_for(self, plan: Plan, grid: tuple[int, int], dtype):
+        """The tiled route's one program per tile grid, keyed on the grid
+        and the extended tile shape, never the page's exact shape."""
+        gh, gw = plan.halo()
+        th, tw = self.config.tile_interior
+        cap = self.config.max_tiles_per_launch
+        key = ("grid", plan, grid, (th + 2 * gh, tw + 2 * gw),
+               np.dtype(dtype).str, cap, self.policy.cache_token(),
+               self.backend, self.interpret)
+
+        def build():
+            # the bucketed route's executor, traced inside the grid program
+            # (built here, not cached: the grid program is the one compile)
+            execute = build_executor(
+                plan,
+                backend=self.backend,
+                policy=self.policy,
+                interpret=self.interpret,
+                with_aux=True,
+            )
+            return build_grid_executor(plan, execute, grid, (th, tw),
+                                       max_tiles_per_launch=cap)
+
+        return self.cache.get(key, build)
 
     def _execute_tiled(self, reqs: list) -> None:
         for r in reqs:
@@ -750,6 +771,8 @@ class MorphService:
                 continue
             if self._injector is not None:
                 self._injector.before_dispatch([r])
+            h, w = r.img.shape
+            ny, nx = tile_counts(h, w, self.config.tile_interior)
             gh, gw = r.plan.halo()
             ext = (self.config.tile_interior[0] + 2 * gh,
                    self.config.tile_interior[1] + 2 * gw)
@@ -757,24 +780,19 @@ class MorphService:
                 self._dspan.attrs.update(bucket=ext,
                                          dtype=np.dtype(r.img.dtype).name)
 
-            aux_chunks: list = []
-
-            def execute(tiles, rects):
-                fn = self._executor_for(r.plan, ext, tiles.dtype, tiles.shape[0])
-                outs, aux = fn(jnp.asarray(tiles), jnp.asarray(rects))
+            def execute(grid, page, rects):
+                fn = self._grid_executor_for(r.plan, grid, page.dtype)
+                outs, aux = fn(page, rects)
                 self._count_device(outs)
-                aux_chunks.append(aux)  # record after all chunks dispatch:
-                return outs             # int(aux) here would sync per launch
+                return outs, aux
 
             try:
-                outs = run_tiled(
+                outs, aux = run_grid(
                     r.img,
                     r.plan,
                     execute,
                     tile_interior=self.config.tile_interior,
-                    launch_batch=self.config.max_tiles_per_launch,
                     stage=lambda name: self._stage(name, r.plan.name),
-                    on_launch=self._count_tiled_launch,
                 )
             except ServeError:
                 raise
@@ -784,11 +802,14 @@ class MorphService:
                     plan=r.plan.name,
                     bucket=ext,
                     dtype=np.dtype(r.img.dtype).name,
-                    batch=self.config.max_tiles_per_launch,
+                    batch=ny * nx,
                 ) from exc
+            self._tiles.inc(ny * nx)
+            self._tile_launches.inc()
+            self._px_valid.inc(h * w)
+            self._px_launched.inc(ny * nx * ext[0] * ext[1])
+            self._record_aux(aux)
             names = r.plan.output_names()
-            for aux in aux_chunks:
-                self._record_aux(aux)
             # record before resolving: a caller returning from result()
             # must observe its own request in stats()
             self._stats.record_tiled([time.monotonic() - r.t_submit])

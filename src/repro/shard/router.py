@@ -319,7 +319,7 @@ class ShardedMorphService:
         survivors = [i for i in range(n) if i != dead and self._healthy(i)]
         out = []
         for token, (plan, bucket, dtype_str) in self._groups.items():
-            if bucket is None:  # tiled groups compile per image; skip
+            if bucket is None:  # tiled groups compile per tile grid; skip
                 continue
             h = zlib.crc32(token)
             if h % n != dead or not survivors:

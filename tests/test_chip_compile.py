@@ -97,3 +97,25 @@ def test_transpose_tiled_u8_compiles(one_chip):
         lambda x: transpose_tiled(x, interpret=False), one_chip, PAPER[1:], jnp.uint8,
         kernels=("transpose_tiled",),
     )
+
+
+def test_a4_grid_program_compiles(one_chip):
+    """The tiled route's one program for an A4 page at 300 dpi (a 7x5 grid
+    of 512² interiors, chunks of 16, 16 and 3): gather, the six fused
+    passes and the stitch compile as one executable, and the page goes in
+    and both outputs come out row-major, so no transfer relayouts them."""
+    from repro.serve.morph import build_executor, get_plan
+    from repro.serve.morph.tiling import build_grid_executor
+
+    plan = get_plan("document_cleanup")
+    execute = build_executor(plan, backend="kernel", policy=FUSED,
+                             interpret=False, with_aux=True)
+    fn = build_grid_executor(plan, execute, (7, 5), (512, 512),
+                             max_tiles_per_launch=16)
+    page = jax.ShapeDtypeStruct((7 * 512, 5 * 512), jnp.uint8, sharding=one_chip)
+    rects = jax.ShapeDtypeStruct((35, 4), jnp.int32, sharding=one_chip)
+    text = fn.lower(page, rects).compile().as_text()
+    for name in ("morph_fused_min", "morph_fused_max"):
+        assert f"%{name}" in text, name
+    layout = text.splitlines()[0].split("entry_computation_layout=")[1]
+    assert layout.count("u8[3584,2560]{1,0") == 3, layout[:300]

@@ -403,7 +403,7 @@ PAGE = (3508, 2480)  # A4 at 300 dpi, portrait
 
 @pytest.mark.parametrize("shape,n,plan", [
     (FRAME, 5, single_op_plan("erode", (3, 3))),  # one (608, 896) batch of 8 slots
-    (PAGE, 1, "document_cleanup"),  # 35 tiles in launches of 16, 16 and 3 + 13 dummies
+    (PAGE, 1, "document_cleanup"),  # 35 tiles in one grid program, no dummies
 ], ids=["frames_batch_of_5", "a4_page"])
 def test_launch_counters_are_exact(shape, n, plan):
     """Pixels answered against pixels launched (slots times bucket or tile
@@ -420,7 +420,7 @@ def test_launch_counters_are_exact(shape, n, plan):
         launched, tiles, launches = 8 * 608 * 896, 0, 0
     else:
         gh, gw = get_plan("document_cleanup").halo()
-        launched, tiles, launches = 3 * 16 * (512 + 2 * gh) * (512 + 2 * gw), 35, 3
+        launched, tiles, launches = 35 * (512 + 2 * gh) * (512 + 2 * gw), 35, 1
     assert snap["executor.pixels_valid"] == n * shape[0] * shape[1]
     assert snap["executor.pixels_launched"] == launched
     assert snap["tiled.tiles"] == tiles
