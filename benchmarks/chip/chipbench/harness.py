@@ -13,8 +13,11 @@ holds the cell's chips::
     IngressClient -> Frontier.serve() -> Frontier -> WorkerHost -> service
 
 over loopback sockets, where the service is ``MorphService(ServiceConfig())``.
-A traced run (``--trace 1``) turns the service's spans and profiler
-annotations on and records a profiler trace of the window.
+A configuration that states ``"replicas": N`` lays the cell out over its
+chips: N services, replica i pinned to chip i, each behind a worker host of
+its own, and one frontier over the N hosts. A traced run (``--trace 1``)
+turns the services' spans and profiler annotations on and records a profiler
+trace of the window.
 """
 from __future__ import annotations
 
@@ -26,11 +29,13 @@ import importlib.util
 import json
 import os
 import queue
+import resource
 import shutil
 import sys
 import tempfile
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
@@ -41,6 +46,7 @@ BENCH_REL = os.path.join("benchmarks", "chip")
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))))
 LATE_S = 60.0  # how long past the window's close an answer may take
+WARM_THREADS = 16  # executors compiled or loaded at once where there are replicas
 
 
 class BenchError(SystemExit):
@@ -118,13 +124,17 @@ def import_program(root: str = ROOT):
 
 def enable_cache(root: str = ROOT) -> str:
     """JAX's persistent cache at a fixed path inside the checkout, unless the
-    environment names one; programs of every size are kept."""
+    environment names one; programs of every size are kept, and none is
+    evicted: a four-chip cell's programs (one set per chip, up to 4 MB each)
+    outgrow a cache capped at a few hundred MB, which would leave every run
+    compiling in set-up."""
     import jax
 
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(root, ".jax_cache")
     os.environ["JAX_COMPILATION_CACHE_DIR"] = path
     jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_compilation_cache_max_size", -1)
     return path
 
 
@@ -149,18 +159,55 @@ def server_plan(kind: dict):
 
 @dataclasses.dataclass
 class Stack:
-    service: object
-    host: object
+    services: list
+    hosts: list
+    frontier: object
     edge: object
     client: object
+    served_by: dict  # trace id -> the replica that took the request (replicas only)
+
+    def trace_events(self) -> list[dict]:
+        """Every service's finished spans; in one process they share a clock.
+        (``Frontier.export_trace`` would need tracing on at the frontier too,
+        which adds its own spans and annotations to every traced cell.)"""
+        return [ev for svc in self.services
+                for ev in (svc.export_trace() or {}).get("traceEvents", [])]
 
     def close(self) -> None:
-        for part in (self.client, self.edge, self.host):
+        for part in (self.client, self.edge, *self.hosts):
             if part is not None:
-                part.close()  # the edge closes its frontier; the host its service
+                part.close()  # the edge closes its frontier; a host its service
 
 
-def open_stack(config: dict, traced: bool, ring: int) -> Stack:
+class Attributed:
+    """A replica as its worker host sees it: notes which replica took each
+    request, by the trace id the benchmark sent, and is the service in all
+    else. The check draws its sample from every replica's answers by it."""
+
+    def __init__(self, service, index: int, served_by: dict):
+        self._service, self._index, self._served_by = service, index, served_by
+
+    def submit_plan(self, img, plan, **kw):
+        self._served_by[kw.get("_trace")] = self._index
+        return self._service.submit_plan(img, plan, **kw)
+
+    def __getattr__(self, name):
+        return getattr(self._service, name)
+
+
+def replicas_of(config: dict) -> int:
+    return int(config.get("replicas", 1))
+
+
+def counter(snapshot: dict, name: str) -> float:
+    m = snapshot.get(name)
+    return m["value"] if m is not None else 0
+
+
+def open_stack(config: dict, traced: bool, ring: int, devices) -> Stack:
+    """The served stack. With one replica the service takes the process's
+    default device; with N, replica i is pinned to ``devices[i]`` (to the one
+    device there is, where fewer are given, as on the CPU)."""
     from repro.obs import ObsConfig
     from repro.serve.ingress import Frontier, IngressClient, WorkerHost
     from repro.serve.morph import MorphService, ServiceConfig
@@ -170,13 +217,20 @@ def open_stack(config: dict, traced: bool, ring: int) -> Stack:
         kw["buckets"] = tuple(tuple(b) for b in kw["buckets"])
     if "tile_interior" in kw:
         kw["tile_interior"] = tuple(kw["tile_interior"])
-    if traced:
-        kw["obs"] = ObsConfig(jax_profiler=True, trace_ring=ring)
-    service = MorphService(ServiceConfig(**kw))
-    host = WorkerHost(service)
+    n = replicas_of(config)
+    services, hosts, served_by = [], [], {}
     frontier = edge = client = None
     try:
-        frontier = Frontier([host.address])
+        for i in range(n):
+            own = dict(kw)
+            if traced:
+                own["obs"] = ObsConfig(jax_profiler=True, trace_ring=ring)
+            if n > 1:
+                own.update(device=devices[i % len(devices)], shard=i)
+            services.append(MorphService(ServiceConfig(**own)))
+            hosts.append(WorkerHost(services[-1] if n == 1 else
+                                    Attributed(services[-1], i, served_by)))
+        frontier = Frontier([h.address for h in hosts])
         edge = frontier.serve()
         client = IngressClient(edge.address)
     except BaseException:
@@ -184,37 +238,49 @@ def open_stack(config: dict, traced: bool, ring: int) -> Stack:
             edge.close()
         elif frontier is not None:
             frontier.close()
-        host.close()
+        for h in hosts:
+            h.close()
         raise
-    return Stack(service, host, edge, client)
+    return Stack(services, hosts, frontier, edge, client, served_by)
 
 
 def warm_executors(stack: Stack, kinds: list[dict], config: dict) -> int:
     """Build and run, on zeros, every executor a bucketed request of this cell
-    can reach: each kind's plan at its bucket, at every power-of-two batch up
-    to ``max_batch``. Returns how many."""
+    can reach, on every replica: each kind's plan at its bucket, at every
+    power-of-two batch up to ``max_batch``. With replicas, several at once.
+    Returns how many."""
     import jax.numpy as jnp
     from repro.serve.morph.buckets import choose_bucket
 
-    svc = stack.service
     h, w = int(config["image"]["height"]), int(config["image"]["width"])
     dtype = np.dtype(config["image"]["dtype"])
-    n = 0
-    for kind in kinds:
-        plan = server_plan(kind)
-        bucket = choose_bucket(h, w, svc.config.buckets)
-        if bucket is None:
-            continue  # tiled: warmed by serving a whole request
-        b = 1
-        while b <= svc.config.max_batch:
-            fn = svc._executor_for(plan, bucket, dtype, b)
-            with svc._device_scope():
-                outs, _ = fn(jnp.zeros((b, *bucket), dtype), jnp.zeros((b, 4), jnp.int32))
-                for v in outs.values():
-                    v.block_until_ready()
-            n += 1
-            b *= 2
-    return n
+    jobs = []
+    for svc in stack.services:
+        for kind in kinds:
+            plan = server_plan(kind)
+            bucket = choose_bucket(h, w, svc.config.buckets)
+            if bucket is None:
+                continue  # tiled: warmed by serving a whole request
+            b = 1
+            while b <= svc.config.max_batch:
+                jobs.append((svc, plan, bucket, b))
+                b *= 2
+
+    def warm(job) -> None:
+        svc, plan, bucket, b = job
+        fn = svc._executor_for(plan, bucket, dtype, b)
+        with svc._device_scope():
+            outs, _ = fn(jnp.zeros((b, *bucket), dtype), jnp.zeros((b, 4), jnp.int32))
+            for v in outs.values():
+                v.block_until_ready()
+
+    if len(stack.services) == 1:
+        for job in jobs:
+            warm(job)
+    elif jobs:
+        with ThreadPoolExecutor(min(len(jobs), WARM_THREADS)) as pool:
+            list(pool.map(warm, jobs))
+    return len(jobs)
 
 
 def annotate(name: str, on: bool):
@@ -245,11 +311,14 @@ class Record:
 
 class Driver:
     """Sends the cell's requests through the client and keeps, for the check,
-    the answers of the ``sample`` finished requests with the lowest draws."""
+    the answers of the ``sample`` finished requests with the lowest draws from
+    each replica that answered any: a replica that takes a small share, such
+    as a reroute target, is compared all the same."""
 
     def __init__(self, stack: Stack, kinds, plans, pool, seed: int, sample: int,
                  traced: bool = False):
         self.client = stack.client
+        self.served_by = stack.served_by
         self.traced = traced
         self.kinds = kinds
         self.plans = plans
@@ -259,7 +328,7 @@ class Driver:
         self.records: list[Record] = []
         self.done_q: queue.Queue = queue.Queue()
         self._lock = threading.Lock()
-        self._heap: list = []  # (-draw, index, outputs): the kept answers
+        self._heaps: dict = {}  # replica -> [(-draw, index, outputs)]: the kept answers
         self.seq: np.ndarray | None = None
 
     def kind_of(self, index: int) -> int:
@@ -290,17 +359,23 @@ class Driver:
             rec.error = f"{type(exc).__name__}: {exc}"
         else:
             draw = gen.check_priority(self.seed, rec.index)
+            replica = self.served_by.get(TRACE_BASE + rec.index, 0)
             with self._lock:
-                if len(self._heap) < self.sample:
-                    heapq.heappush(self._heap, (-draw, rec.index, fut.result()))
-                elif -self._heap[0][0] > draw:
-                    heapq.heapreplace(self._heap, (-draw, rec.index, fut.result()))
+                heap = self._heaps.setdefault(replica, [])
+                if len(heap) < self.sample:
+                    heapq.heappush(heap, (-draw, rec.index, fut.result()))
+                elif -heap[0][0] > draw:
+                    heapq.heapreplace(heap, (-draw, rec.index, fut.result()))
         rec.done = t
         self.done_q.put(rec.index)
 
     def kept(self) -> list[tuple[int, object]]:
         with self._lock:
-            return sorted((i, r) for _, i, r in self._heap)
+            return sorted((i, r) for heap in self._heaps.values() for _, i, r in heap)
+
+    def kept_per_replica(self) -> dict[int, int]:
+        with self._lock:
+            return {k: len(heap) for k, heap in sorted(self._heaps.items())}
 
     def outstanding(self) -> int:
         return sum(1 for r in self.records if r.done is None)
@@ -392,10 +467,7 @@ class Run:
         return sum(per_kind[r.kind] for r in recs)
 
     def counter_delta(self, name: str) -> float:
-        def v(snap):
-            m = snap.get(name)
-            return m["value"] if m is not None else 0
-        return v(self.counters1) - v(self.counters0)
+        return counter(self.counters1, name) - counter(self.counters0, name)
 
 
 def out_itemsize(op: str, in_item: int) -> int:
@@ -413,6 +485,24 @@ def ideal_bytes(kind: dict, pixels: int, item: int, plans: dict) -> int:
     else:
         outs = [out_itemsize(kind["op"], item)]
     return pixels * (item + sum(outs))
+
+
+def host_usage(ru0, ru1) -> str:
+    """The process's CPU time, page faults and context switches between two
+    ``getrusage`` readings."""
+    return (f"user {ru1.ru_utime - ru0.ru_utime} s, sys {ru1.ru_stime - ru0.ru_stime} s, "
+            f"minor faults {ru1.ru_minflt - ru0.ru_minflt}, major faults "
+            f"{ru1.ru_majflt - ru0.ru_majflt}, voluntary switches "
+            f"{ru1.ru_nvcsw - ru0.ru_nvcsw}, involuntary {ru1.ru_nivcsw - ru0.ru_nivcsw}, "
+            f"cpus allowed {len(os.sched_getaffinity(0))}")
+
+
+def answers_per_bin(run: Run, width: float) -> list[int]:
+    """Answers that arrived inside the window, counted in bins of ``width`` s."""
+    bins = [0] * max(1, int(np.ceil((run.t1 - run.t0) / width)))
+    for r in run.completed_in_window():
+        bins[min(len(bins) - 1, int((r.done - run.t0) // width))] += 1
+    return bins
 
 
 def check_answers(driver: Driver, kinds, plans, pool, make=None):
@@ -436,6 +526,9 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool, *,
 
     config, mix, cell = spec["config"], spec["traffic"], spec["cell"]
     chips = int(cell["chips"])
+    if replicas_of(config) > chips:
+        raise BenchError(f"the configuration asks for {replicas_of(config)} replicas, "
+                         f"the cell has {chips} chips")
     if require_tpu:
         devices = chip_devices(chips)
     else:
@@ -454,7 +547,7 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool, *,
     due = (gen.open_loop_due(float(mix["rate_per_s"]), seconds, seed)
            if open_loop else None)
     ring = 4 * (len(due) if open_loop else int(seconds * 20_000)) + 4096
-    stack = open_stack(config, trace, ring)
+    stack = open_stack(config, trace, ring, devices)
     try:
         warmed = warm_executors(stack, kinds, config)
         # every kind once through the whole stack: sockets, crops, tiles
@@ -468,18 +561,21 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool, *,
         if trace:
             logdir = tempfile.mkdtemp(prefix="chipbench_trace_")
             jax.profiler.start_trace(logdir)
-        counters0 = stack.service.metrics_snapshot()
+        # the fleet's view: the replicas' counters summed, by metric type
+        counters0, fleet0 = stack.frontier.metrics_snapshot(), stack.frontier.stats()
         setup_s = time.perf_counter() - t_start
         s_c, n_c, n_h = meter.reading()
         print(f"set-up: {setup_s} s, {warmed} executors warmed, {n_c} programs "
               f"obtained ({n_h} from the persistent cache), compile {s_c} s",
               file=sys.stderr, flush=True)
+        ru0 = resource.getrusage(resource.RUSAGE_SELF)
         with annotate("bench:window", trace):
             if open_loop:
                 t0, t1 = driver.open_loop(due, seconds)
             else:
                 t0, t1 = driver.closed_loop(int(mix["in_flight"]), seconds)
-            counters1 = stack.service.metrics_snapshot()
+            counters1, fleet1 = stack.frontier.metrics_snapshot(), stack.frontier.stats()
+        ru1 = resource.getrusage(resource.RUSAGE_SELF)
         in_window = meter.compiles_between(t0, t1)
         driver.drain(t1 + LATE_S)
         trace_red = spans = None
@@ -489,8 +585,7 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool, *,
             shutil.rmtree(logdir, ignore_errors=True)
             names = [f"{xtrace.DEVICE_PREFIX}{d.id}" for d in devices]
             trace_red = xtrace.reduce(raw, names)
-            doc = stack.service.export_trace() or {}
-            spans = doc.get("traceEvents", [])
+            spans = stack.trace_events()
         mem = [d.memory_stats() or {} for d in devices] if require_tpu else []
         memory_peak = max((m.get("peak_bytes_in_use", 0) for m in mem), default=0)
     finally:
@@ -524,6 +619,15 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool, *,
           f"max {max(lateness, default=0.0)} s, mean "
           f"{sum(lateness) / len(lateness) if lateness else 0.0} s; check {check_s} s",
           file=sys.stderr)
+    per_replica = [(b or {}).get("requests", 0) - (a or {}).get("requests", 0)
+                   for a, b in zip(fleet0["per_worker"], fleet1["per_worker"])]
+    print(f"replicas: requests in the window {per_replica}; frontier reroutes "
+          f"{fleet1['reroutes']}, failovers {fleet1['failovers']}; answers compared "
+          f"{driver.kept_per_replica()}", file=sys.stderr)
+    print(f"host over the window: {host_usage(ru0, ru1)}; answers per 5 s "
+          f"{answers_per_bin(run, 5.0)}", file=sys.stderr)
+    if trace_red is not None:
+        print(f"busy s per chip: {trace_red['busy_s_per_device']}", file=sys.stderr)
     if errors:
         print(f"errors: {errors}", file=sys.stderr)
     for name, c in checks.items():

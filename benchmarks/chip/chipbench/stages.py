@@ -20,14 +20,16 @@ def ingress_by_request(run) -> list[tuple[object, dict]]:
             if TRACE_BASE + r.index in by_trace]
 
 
-def covered_share(run, name: str) -> float | None:
-    """Share of the window covered by the union of the spans called
-    ``name``; None where there are none."""
+def covered_shares(run, name: str) -> dict[str, float]:
+    """Share of the window covered by the union of the spans called ``name``,
+    for each service (its tracer's ``pid``: one replica each); empty where
+    there are none."""
     if not run.spans:
-        return None
-    evs = [(name, ev["ts"], ev["dur"]) for ev in run.spans
-           if ev.get("name") == name and ev.get("ph") == "X"]
-    if not evs:
-        return None
+        return {}
+    by_pid: dict[str, list] = {}
+    for ev in run.spans:
+        if ev.get("name") == name and ev.get("ph") == "X":
+            by_pid.setdefault(ev.get("pid"), []).append((name, ev["ts"], ev["dur"]))
     t0, t1 = run.t0 * 1e6, run.t1 * 1e6
-    return xtrace.busy_ns(evs, t0, t1) / (t1 - t0)  # a union in the events' unit
+    # a union in the events' unit
+    return {pid: xtrace.busy_ns(evs, t0, t1) / (t1 - t0) for pid, evs in by_pid.items()}

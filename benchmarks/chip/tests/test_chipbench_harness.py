@@ -105,6 +105,11 @@ def small_spec(workload):
         cfg["image"].update(height=150, width=200)
         cfg["service"] = {"buckets": [[64, 128]], "tile_interior": [64, 64],
                           "max_tiles_per_launch": 4}
+    elif "replicas" in cfg:  # two replicas on the one CPU device, both in use:
+        cfg["replicas"] = 2  # erode and dilate hash apart at the (128, 128) bucket
+        cfg["image"].update(height=100, width=120)
+        cfg["requests"][0]["se_sizes"] = [3, 15]
+        cfg["service"] = {"max_batch": 4}
     else:
         cfg["image"].update(height=60, width=80)
         cfg["requests"][0]["se_sizes"] = [3, 15]
@@ -121,7 +126,7 @@ def run_small(workload, **kw):
                             t_start=time.perf_counter(), require_tpu=False, **kw)
 
 
-CELLS = ["paper.closed64", "a4page.closed8", "paper.poisson80"]
+CELLS = ["paper.closed64", "a4page.closed8", "paper.poisson80", "paper4.closed256"]
 
 
 @pytest.mark.parametrize("workload", CELLS)
@@ -223,3 +228,215 @@ def test_failed_answers_are_not_correct(workload, dropped_answers):
     assert out["correct"] is False
     assert out["failed"] > 0
     assert out["checks"]["failed_requests"]["value"] == out["failed"]
+
+
+# ------------------------------------------------- the layout over replicas
+def replica_stack(traced=False):
+    import jax
+
+    harness.import_program(ROOT)
+    cfg = small_spec("paper4.closed256")["config"]
+    return cfg, harness.open_stack(cfg, traced, 4096, jax.devices()[:4])
+
+
+def test_replicas_are_pinned_and_behind_one_frontier():
+    import jax
+
+    cfg, stack = replica_stack()
+    try:
+        assert [s.config.shard for s in stack.services] == [0, 1]
+        assert all(s.config.device == jax.devices()[0] for s in stack.services)
+        assert [link.address for link in stack.frontier.links] == [h.address
+                                                                   for h in stack.hosts]
+    finally:
+        stack.close()
+    cfg1 = small_spec("paper.closed64")["config"]
+    stack = harness.open_stack(cfg1, False, 4096, jax.devices()[:1])
+    try:  # one replica: the process's default device, no shard, as before
+        (svc,) = stack.services
+        assert svc.config.device is None and svc.config.shard is None
+    finally:
+        stack.close()
+
+
+def test_counters_are_the_sum_over_replicas():
+    from chipbench import traffic as gen
+
+    cfg, stack = replica_stack()
+    try:
+        before = stack.frontier.metrics_snapshot()
+        kinds = gen.request_kinds(cfg)
+        imgs = gen.image_pool(cfg, 2, 5)
+        futs = [stack.client.submit_plan(imgs[i % 2], harness.server_plan(k))
+                for i, k in enumerate(kinds * 2)]
+        futs += [stack.services[1].submit_plan(imgs[0], harness.server_plan(kinds[0]))]
+        for f in futs:
+            f.result(timeout=120)
+        snaps = [svc.metrics_snapshot() for svc in stack.services]
+        each = [harness.counter(s, "requests") for s in snaps]
+        assert all(n > 0 for n in each)  # both replicas served
+        total = stack.frontier.metrics_snapshot()
+        assert harness.counter(total, "requests") == sum(each) == 2 * len(kinds) + 1
+        assert harness.counter(before, "requests") == 0
+        px = [harness.counter(s, "executor.pixels_launched") for s in snaps]
+        assert harness.counter(total, "executor.pixels_launched") == sum(px) > 0
+        assert [w["requests"] for w in stack.frontier.stats()["per_worker"]] == each
+    finally:
+        stack.close()
+
+
+def test_every_serving_replica_is_sampled():
+    """The check keeps the lowest draws of each replica that answered: one
+    answer from a replica that took almost nothing is compared, though a
+    sample drawn over all answers would leave it out."""
+    import types
+    from concurrent.futures import Future
+
+    from chipbench import traffic as gen
+    from chipbench.spans import TRACE_BASE
+
+    seed, n, sample = 2**33 + 5, 200, 4
+    served_by = {}
+    stack = types.SimpleNamespace(client=None, served_by=served_by)
+    driver = harness.Driver(stack, [{}], [None], [], seed, sample)
+    draws = {i: gen.check_priority(seed, i) for i in range(n)}
+    rare = max(draws, key=draws.get)
+    for i in range(n):
+        served_by[TRACE_BASE + i] = 3 if i == rare else i % 2
+        fut = Future()
+        fut.set_result(i)
+        driver._finished(harness.Record(i, 0, due=0.0), fut)
+    kept = [i for i, _ in driver.kept()]
+    assert rare in kept
+    assert driver.kept_per_replica() == {0: sample, 1: sample, 3: 1}
+    for r in (0, 1):
+        own = sorted((i for i in range(n) if i != rare and i % 2 == r), key=draws.get)
+        assert sorted(i for i in kept if i != rare and i % 2 == r) == sorted(own[:sample])
+
+
+def test_one_snapshot_sums_to_itself():
+    """One replica's counters read as they did before the sum."""
+    harness.import_program(ROOT)
+    from repro.serve.ingress.stats import merge_worker_metrics
+
+    snap = {"requests": {"type": "counter", "value": 7},
+            "batches": {"type": "counter", "value": 3}}
+    assert merge_worker_metrics([snap]) == snap
+
+
+def test_every_replica_warms_every_kind():
+    from chipbench import traffic as gen
+
+    cfg, stack = replica_stack()
+    try:
+        kinds = gen.request_kinds(cfg)
+        warmed = harness.warm_executors(stack, kinds, cfg)
+        batches = [1, 2, 4]  # every power of two up to max_batch 4
+        assert warmed == 2 * len(kinds) * len(batches)
+        for svc in stack.services:
+            for kind in kinds:
+                for b in batches:
+                    key = svc._executor_key(harness.server_plan(kind), (128, 128),
+                                            np.uint8, b)
+                    assert key in svc.cache._entries
+            assert svc.cache.misses == len(kinds) * len(batches)
+    finally:
+        stack.close()
+
+
+def test_traced_spans_come_from_every_replica():
+    from chipbench import traffic as gen
+
+    cfg, stack = replica_stack(traced=True)
+    try:
+        kinds = gen.request_kinds(cfg)
+        img = gen.image_pool(cfg, 1, 3)[0]
+        for f in [stack.client.submit_plan(img, harness.server_plan(k)) for k in kinds]:
+            f.result(timeout=120)
+        pids = {ev["pid"] for ev in stack.trace_events()
+                if ev.get("name") == "dispatch" and ev.get("ph") == "X"}
+        assert pids == {"0", "1"}
+    finally:
+        stack.close()
+
+
+def test_more_replicas_than_chips_is_an_error():
+    spec = small_spec("paper4.closed256")
+    spec["config"]["replicas"] = int(spec["cell"]["chips"]) + 1
+    harness.import_program(ROOT)
+    with pytest.raises(SystemExit, match="replicas"):
+        harness.run_cell(spec, 3, 0.2, False, t_start=time.perf_counter(),
+                         require_tpu=False)
+
+
+@pytest.fixture
+def one_replica_altered(monkeypatch):
+    """Replica 1's executors alone alter one pixel in the middle of each
+    image where the answer is produced; replica 0 is sound."""
+    from repro.serve.morph.service import MorphService
+
+    real = MorphService._executor_for
+
+    def executor_for(self, *a, **kw):
+        fn = real(self, *a, **kw)
+        if self.config.shard != 1:
+            return fn
+
+        def broken(x, rect):
+            outs, aux = fn(x, rect)
+            h, w = x.shape[1] // 2, x.shape[2] // 2
+            return {k: v.at[:, h, w].add(1) for k, v in outs.items()}, aux
+        return broken
+
+    monkeypatch.setattr(MorphService, "_executor_for", executor_for)
+
+
+def test_one_replica_altered_is_not_correct(one_replica_altered):
+    """The check draws its sample from each replica's answers: with one
+    answer kept a replica, replica 1's altered answer is among them."""
+    spec = small_spec("paper4.closed256")
+    spec["traffic"]["check_sample"] = 1
+    harness.import_program(ROOT)
+    out = harness.run_cell(spec, 2**33 + 78, 0.6, False, t_start=time.perf_counter(),
+                           require_tpu=False)
+    assert out["correct"] is False
+    assert out["checks"]["wrong_pixels"]["value"] > 0
+
+
+# ------------------------------------------------------- the process's environment
+def test_a_configuration_states_its_process_environment():
+    from chipbench import launch
+
+    page = launch.stated_environment(["--workload", "a4page.closed8", "--seed", "1"], ROOT)
+    assert page["GLIBC_TUNABLES"].startswith("glibc.malloc.arena_max=1:")
+    assert launch.stated_environment(["--workload", "paper.closed64"], ROOT) == {}
+    assert launch.stated_environment(["--workload", "no.such.cell"], ROOT) == {}
+    assert launch.stated_environment([], ROOT) == {}
+
+
+def test_relaunch_execs_only_where_a_stated_value_is_missing(monkeypatch):
+    from chipbench import launch
+
+    class Execed(Exception):
+        pass
+
+    def execve(path, args, env):
+        raise Execed(path, args, env)
+
+    monkeypatch.setattr(os, "execve", execve)
+    argv = ["--workload", "a4page.closed8", "--seed", "3", "--seconds", "1"]
+    stated = launch.stated_environment(argv, ROOT)
+    monkeypatch.delenv("GLIBC_TUNABLES", raising=False)
+    with pytest.raises(Execed) as ex:
+        launch.relaunch(argv, ROOT, 12.5)
+    path, args, env = ex.value.args
+    assert path == sys.executable and args[-len(argv):] == argv
+    assert env["GLIBC_TUNABLES"] == stated["GLIBC_TUNABLES"]
+    assert float(env[launch.T_START_VAR]) == 12.5
+    # the new image: the value is there, set-up counts from the first start
+    monkeypatch.setenv("GLIBC_TUNABLES", stated["GLIBC_TUNABLES"])
+    monkeypatch.setenv(launch.T_START_VAR, "12.5")
+    assert launch.relaunch(argv, ROOT, 99.0) == 12.5
+    assert launch.T_START_VAR not in os.environ
+    # a cell that states nothing runs where it started
+    assert launch.relaunch(["--workload", "paper.closed64"], ROOT, 7.0) == 7.0

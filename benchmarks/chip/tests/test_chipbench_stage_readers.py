@@ -25,8 +25,8 @@ def ingress(index, ts_us, dur_us, recv_us, reply_us):
                      "stages": {"recv": recv_us, "reply": reply_us}}}
 
 
-def dispatch(ts_us, dur_us):
-    return {"name": "dispatch", "ph": "X", "ts": ts_us, "dur": dur_us,
+def dispatch(ts_us, dur_us, pid="0"):
+    return {"name": "dispatch", "ph": "X", "ts": ts_us, "dur": dur_us, "pid": pid,
             "args": {"trace_ids": [TRACE_BASE], "stages": {"pad": 1.0}}}
 
 
@@ -85,3 +85,52 @@ def test_reader_reads_nothing_from_a_program_without_the_spans(metric):
                    "args": {"trace_id": TRACE_BASE}}]
     assert read(hand_run(counters(requests=1), counters(requests=9), queue_only)) is None
     assert read(hand_run()) is None
+
+
+def test_dispatch_busy_reads_the_busiest_replica():
+    """Each replica's dispatch thread on its own lane: the busiest one's
+    share, not the union over the threads (which would read 40)."""
+    spans = [dispatch(100.0e6, 300e3, pid="0"), dispatch(100.2e6, 200e3, pid="1"),
+             dispatch(100.6e6, 50e3, pid="1")]
+    read = harness.load_reader("dispatch_busy.tput", BENCH)
+    assert read(hand_run(spans=spans)) == pytest.approx(30.0)
+    assert read(hand_run(spans=spans[1:])) == pytest.approx(25.0)
+
+
+def trace_run(busy_s):
+    """A run whose trace reduction gives each of the cell's chips its busy
+    seconds."""
+    run = hand_run()
+    run.trace = {"window_s": 1.0, "busy_s": sum(busy_s) / len(busy_s),
+                 "busy_s_per_device": {f"/device:TPU:{i}": b for i, b in enumerate(busy_s)}}
+    return run
+
+
+@pytest.mark.parametrize("busy_s,expected", [
+    ([0.2, 0.2, 0.2, 0.2], 25.0),    # even load over four chips
+    ([0.0, 0.3, 0.3, 0.0], 50.0),    # two of four chips do the work
+    ([0.0, 0.0, 0.45, 0.0], 100.0),  # one chip does everything
+    ([0.1, 0.3, 0.5, 0.1], 50.0),
+])
+def test_busiest_chip_share(busy_s, expected):
+    read = harness.load_reader("busiest_chip_share.tput", BENCH)
+    assert read(trace_run(busy_s)) == pytest.approx(expected)
+
+
+def test_busiest_chip_share_from_a_reduced_trace():
+    from chipbench import xtrace
+
+    trace = {"devices": {"/device:TPU:0": [["fusion.1", 100, 300]],
+                         "/device:TPU:1": [["fusion.1", 0, 100], ["copy", 500, 200]],
+                         "/device:TPU:2": [], "/device:TPU:3": []},
+             "host": [["bench:window", 0, 1000]]}
+    run = hand_run()
+    run.trace = xtrace.reduce(trace, [f"/device:TPU:{i}" for i in range(4)])
+    read = harness.load_reader("busiest_chip_share.tput", BENCH)
+    assert read(run) == pytest.approx(100.0 * 300 / 600)
+
+
+def test_busiest_chip_share_reads_nothing_without_busy_time():
+    read = harness.load_reader("busiest_chip_share.tput", BENCH)
+    assert read(trace_run([0.0, 0.0, 0.0, 0.0])) is None
+    assert read(hand_run()) is None  # an untraced run
