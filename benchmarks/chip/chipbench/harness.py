@@ -3,9 +3,11 @@ check against the plain reference, and one JSON line.
 
 The cell, its configuration, its traffic mix and its metrics are all found by
 name: ``BENCHMARK.json`` at the checkout's root names them, and the files are
-``configs/<config>.json``, ``traffic/<traffic>.json`` and
-``metrics/<metric>.py`` under this benchmark's directory (a metric named
-``base.suffix`` may share the reader ``metrics/<base>.py``).
+``configs/<config>.json``, ``traffic/<traffic>.json``,
+``content/<content>.py`` (the configuration's ``image.content``, see
+``traffic.image_pool``) and ``metrics/<metric>.py`` under this benchmark's
+directory (a metric named ``base.suffix`` may share the reader
+``metrics/<base>.py``).
 
 The entry the window drives is the same in every cell, in one process that
 holds the cell's chips::
@@ -25,7 +27,6 @@ import argparse
 import contextlib
 import dataclasses
 import heapq
-import importlib.util
 import json
 import os
 import queue
@@ -39,7 +40,7 @@ from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
-from chipbench import reference, traffic as gen, xtrace
+from chipbench import BenchError, load_file, reference, traffic as gen, xtrace
 from chipbench.spans import TRACE_BASE
 
 BENCH_REL = os.path.join("benchmarks", "chip")
@@ -47,13 +48,6 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))))
 LATE_S = 60.0  # how long past the window's close an answer may take
 WARM_THREADS = 16  # executors compiled or loaded at once where there are replicas
-
-
-class BenchError(SystemExit):
-    """A run that cannot produce a result: exits non-zero, prints no line."""
-
-    def __init__(self, msg: str):
-        super().__init__(f"benchmark: {msg}")
 
 
 # ------------------------------------------------------------------ loading
@@ -98,10 +92,7 @@ def load_reader(name: str, bench_dir: str):
     for stem in (name, name.split(".", 1)[0]):
         path = os.path.join(bench_dir, "metrics", stem + ".py")
         if os.path.exists(path):
-            spec = importlib.util.spec_from_file_location(f"chipbench_metric_{stem}", path)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            return mod.read
+            return load_file(path, f"chipbench_metric_{stem}").read
     raise BenchError(f"no reader for metric {name!r} under {bench_dir}/metrics")
 
 
@@ -471,8 +462,9 @@ class Run:
 
 
 def out_itemsize(op: str, in_item: int) -> int:
-    """Bytes per pixel of an operator's answer: a gradient is widened."""
-    return 2 * in_item if op == "gradient" else in_item
+    """Bytes per pixel of an operator's answer: a gradient is answered in
+    int32, as the program widens bool and every integer narrower than 4 bytes."""
+    return 4 if op == "gradient" else in_item
 
 
 def ideal_bytes(kind: dict, pixels: int, item: int, plans: dict) -> int:
@@ -542,7 +534,7 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool, *,
     kinds = gen.request_kinds(config)
     plans_ref = config.get("plans", {})
     server_plans = [server_plan(k) for k in kinds]
-    pool = gen.image_pool(config, int(mix.get("image_pool", 16)), seed)
+    pool = gen.image_pool(config, int(mix.get("image_pool", 16)), seed, spec["bench_dir"])
     open_loop = mix["loop"] == "open"
     due = (gen.open_loop_due(float(mix["rate_per_s"]), seconds, seed)
            if open_loop else None)

@@ -3,8 +3,11 @@ the program.
 
 Flat rectangular structuring elements centred on the pixel (odd sizes), and
 the border outside the image ignored: each pass pads with its own neutral
-element (the dtype's max for erosion, its min for dilation). A rectangle's
-min is the min of its rows' mins, so each pass runs over rows, then columns.
+element (the dtype's max for erosion, its min for dilation; on a bool mask,
+True for erosion and False for dilation, where ``np.minimum`` and
+``np.maximum`` are and and or). A rectangle's min is the min of its rows'
+mins, so each pass runs over rows, then columns. Every pixel type the service
+takes is covered: u8, u16, i8, i16 and bool.
 """
 from __future__ import annotations
 
@@ -34,19 +37,19 @@ def _slide(x: np.ndarray, w: int, axis: int, fn, neutral) -> np.ndarray:
 
 
 def erode(x: np.ndarray, se) -> np.ndarray:
-    hi = np.iinfo(x.dtype).max
+    hi = True if x.dtype == np.bool_ else np.iinfo(x.dtype).max
     return _slide(_slide(x, int(se[0]), 0, np.minimum, hi), int(se[1]), 1, np.minimum, hi)
 
 
 def dilate(x: np.ndarray, se) -> np.ndarray:
-    lo = np.iinfo(x.dtype).min
+    lo = False if x.dtype == np.bool_ else np.iinfo(x.dtype).min
     return _slide(_slide(x, int(se[0]), 0, np.maximum, lo), int(se[1]), 1, np.maximum, lo)
 
 
 def gradient(x: np.ndarray, se) -> np.ndarray:
-    """Dilation minus erosion, in a type wide enough for the difference."""
-    wide = np.int16 if x.dtype.itemsize == 1 else np.int32
-    return dilate(x, se).astype(wide) - erode(x, se).astype(wide)
+    """Dilation minus erosion as int32: the type the service answers a
+    gradient in, as it widens bool and every integer narrower than 4 bytes."""
+    return dilate(x, se).astype(np.int32) - erode(x, se).astype(np.int32)
 
 
 OPS = {
@@ -77,11 +80,30 @@ def expected(x: np.ndarray, kind: dict, plans: dict) -> dict[str, np.ndarray]:
     return {"out": OPS[kind["op"]](x, kind["se"])}
 
 
+def _narrower(se) -> list[int]:
+    return [max(1, int(s) - 2) for s in se]
+
+
 def control(x: np.ndarray, kind: dict, plans: dict) -> dict[str, np.ndarray]:
-    """The reference one precision step down: pixels kept to their top four
-    bits, as a 4-bit path would hold them (the configuration states 8-bit
-    pixels and exact answers). The comparison has to refuse it."""
-    return expected(x & np.uint8(0xF0), kind, plans)
+    """The reference one step below what the configuration states (exact
+    answers on its pixel type). The comparison has to refuse it.
+
+    - Integers: pixels kept to the top half of their bits, as a path of half
+      the width would hold them: ``0xF0`` on 8 bits, ``0xFF00`` on 16; a
+      signed type takes the same mask on its bits.
+    - bool has no lower precision: the reference with each SE extent cut by 2
+      (not below 1), a wrong window."""
+    if x.dtype == np.bool_:
+        if "plan" in kind:
+            plans = {**plans, kind["plan"]: [{**st, "se": _narrower(st["se"])}
+                                             for st in plans[kind["plan"]]]}
+        else:
+            kind = {**kind, "se": _narrower(kind["se"])}
+        return expected(x, kind, plans)
+    bits = 8 * x.dtype.itemsize
+    unsigned = np.dtype(f"u{x.dtype.itemsize}")
+    mask = unsigned.type(((1 << bits) - 1) ^ ((1 << bits // 2) - 1))
+    return expected((x.view(unsigned) & mask).view(x.dtype), kind, plans)
 
 
 def mismatches(got: dict, want: dict) -> int:

@@ -4,14 +4,18 @@ traffic mix and the seed.
 Every seed gets the same work in another order. The request kinds are dealt
 in shuffled blocks, one of each kind per block, and an open loop's gaps are
 the exponential quantiles of the stated rate, shuffled; so a seed changes the
-order of arrivals and kinds, never their totals.
+order of arrivals and kinds, never their totals. The pixels come from the
+configuration's own content generator, found by name (``image_pool``).
 """
 from __future__ import annotations
 
 import math
+import os
 import zlib
 
 import numpy as np
+
+from chipbench import BenchError, load_file
 
 SEED_MOD = 2**63  # seeds are any whole number; numpy takes it in 64 bits
 
@@ -57,75 +61,38 @@ def open_loop_due(rate_per_s: float, seconds: float, seed: int) -> np.ndarray:
     return np.concatenate([[0.0], np.cumsum(gaps)[:-1]])
 
 
-def smooth_frames(h: int, w: int, count: int, seed: int) -> np.ndarray:
-    """Grayscale frames with structure at every scale the SE sweep spans: a
-    coarse random field upsampled, blobs and pixel noise (uniform noise
-    would erode to a constant under large SEs and hide a wrong window)."""
-    rng = rng_for(seed, "frames")
-    out = np.empty((count, h, w), dtype=np.uint8)
-    yy = np.linspace(0, 1, h)[:, None]
-    xx = np.linspace(0, 1, w)[None, :]
-    for i in range(count):
-        coarse = rng.uniform(40, 215, (9, 12))
-        cy = np.interp(np.linspace(0, 8, h), np.arange(9), np.arange(9))
-        cx = np.interp(np.linspace(0, 11, w), np.arange(12), np.arange(12))
-        y0 = np.floor(cy).astype(int).clip(0, 7)
-        x0 = np.floor(cx).astype(int).clip(0, 10)
-        fy = (cy - y0)[:, None]
-        fx = (cx - x0)[None, :]
-        base = ((1 - fy) * (1 - fx) * coarse[y0][:, x0]
-                + (1 - fy) * fx * coarse[y0][:, x0 + 1]
-                + fy * (1 - fx) * coarse[y0 + 1][:, x0]
-                + fy * fx * coarse[y0 + 1][:, x0 + 1])
-        for _ in range(12):
-            by, bx = rng.uniform(0, 1, 2)
-            r = rng.uniform(0.02, 0.12)
-            base += rng.uniform(-60, 60) * (((yy - by) ** 2 + (xx - bx) ** 2) < r * r)
-        base += rng.normal(0, 12, (h, w))
-        out[i] = np.clip(base, 0, 255).astype(np.uint8)
-    return out
-
-
-def scanned_pages(h: int, w: int, count: int, seed: int) -> np.ndarray:
-    """Scanned text pages: paper-white background with grain, lines of
-    glyph-sized ink strokes, and salt-and-pepper specks."""
-    rng = rng_for(seed, "pages")
-    out = np.empty((count, h, w), dtype=np.uint8)
-    cell_h, cell_w, line_h = 6, 4, 54  # 300 dpi: ~36 px glyphs, 1.5 line pitch
-    for i in range(count):
-        page = rng.normal(232, 6, (h, w))
-        ink = np.zeros((h, w), dtype=bool)
-        top, left = 180, 150  # margins
-        for y in range(top, h - top - 36, line_h):
-            glyphs = rng.random((36 // cell_h, (w - 2 * left) // cell_w)) < 0.38
-            spaces = rng.random(glyphs.shape[1]) < 0.12
-            glyphs[:, spaces] = False
-            block = np.repeat(np.repeat(glyphs, cell_h, 0), cell_w, 1)
-            ink[y:y + block.shape[0], left:left + block.shape[1]] = block
-        page[ink] = rng.normal(35, 10, int(ink.sum()))
-        specks = rng.random((h, w))
-        page[specks < 0.002] = 0
-        page[specks > 0.998] = 255
-        out[i] = np.clip(page, 0, 255).astype(np.uint8)
-    return out
-
-
-GENERATORS = {"smooth_frames": smooth_frames, "scanned_pages": scanned_pages}
-
-
-def image_pool(config: dict, count: int, seed: int) -> np.ndarray:
+def image_pool(config: dict, count: int, seed: int, bench_dir: str) -> np.ndarray:
+    """``count`` images of the configuration's ``image`` entry from its content
+    generator, found by name: ``make(image, count, seed)`` in
+    ``content/<image["content"]>.py`` under ``bench_dir``. The generator gets
+    the whole entry, so it may read keys of its own."""
     im = config["image"]
-    gen = GENERATORS[im["content"]]
-    return gen(int(im["height"]), int(im["width"]), count, seed)
+    path = os.path.join(bench_dir, "content", im["content"] + ".py")
+    if not os.path.exists(path):
+        raise BenchError(f"no content generator {im['content']!r} under {bench_dir}/content")
+    pool = load_file(path, f"chipbench_content_{im['content']}").make(im, count, seed)
+    want = (np.dtype(im["dtype"]), (count, int(im["height"]), int(im["width"])))
+    got = (getattr(pool, "dtype", None), getattr(pool, "shape", None))
+    if got != want:
+        raise BenchError(f"content {im['content']!r} made {got[0]} {got[1]}, "
+                         f"the configuration states {want[0]} {want[1]}")
+    return pool
 
 
 def stamp(base: np.ndarray, index: int) -> np.ndarray:
     """A copy of ``base`` made unique to request ``index``: its number written
-    into eight pixels of a row chosen by it, so no two requests of a run send
-    the same bytes."""
+    into a row chosen by it, so no two requests of a run send the same bytes.
+    Integer pixels take its eight bytes, one a pixel; a bool mask takes its 64
+    bits, one a pixel, running on into the next row where the row is narrower."""
     img = base.copy()
     row = index % img.shape[0]
-    img[row, :8] = np.frombuffer(int(index).to_bytes(8, "little"), dtype=np.uint8)
+    data = np.frombuffer(int(index).to_bytes(8, "little"), dtype=np.uint8)
+    if img.dtype == np.bool_:
+        bits = np.unpackbits(data, bitorder="little").astype(bool)
+        at = (row * img.shape[1] + np.arange(bits.size)) % img.size
+        img.reshape(-1)[at] = bits
+    else:
+        img[row, :8] = data
     return img
 
 

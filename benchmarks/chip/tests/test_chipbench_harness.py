@@ -57,32 +57,45 @@ def test_unknown_cell_and_unknown_device_are_errors():
 def test_ideal_bytes_count_the_request_not_the_bucket():
     px = 800 * 600
     assert harness.ideal_bytes({"op": "erode", "se": [3, 3]}, px, 1, {}) == 2 * px
-    assert harness.ideal_bytes({"op": "gradient", "se": [3, 3]}, px, 1, {}) == 3 * px
+    # a bare gradient is answered in int32: 1 byte read, 4 written
+    assert harness.ideal_bytes({"op": "gradient", "se": [3, 3]}, px, 1, {}) == 5 * px
+    assert harness.ideal_bytes({"op": "gradient", "se": [3, 3]}, px, 2, {}) == 6 * px
     cfg = harness.cell_spec(manifest(), "a4page.closed8", ROOT)["config"]
     page = 2480 * 3508
     assert harness.ideal_bytes({"plan": "document_cleanup"}, page, 1, cfg["plans"]) == 3 * page
 
 
-def test_a_cell_added_from_files_alone(tmp_path):
+def checkout_with(tmp_path, config: dict, traffic: dict, content: str | None = None):
+    """A copy of the benchmark, with a configuration, a traffic mix and, where
+    given, a content generator added as files, and a cell of them in its
+    BENCHMARK.json: what a later PR that adds a cell brings."""
     root = tmp_path / "checkout"
     shutil.copytree(BENCH, root / "benchmarks" / "chip",
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
     m = manifest()
     bench = root / "benchmarks" / "chip"
-    (bench / "configs" / "tiny_u8.json").write_text(json.dumps({
+    name = config["name"]
+    (bench / "configs" / f"{name}.json").write_text(json.dumps(config))
+    (bench / "traffic" / "closed2.json").write_text(json.dumps(traffic))
+    if content is not None:
+        (bench / "content" / f"{config['image']['content']}.py").write_text(content)
+    m["configs"].append({"name": name, "source": "https://example.org/tiny",
+                         "file": f"benchmarks/chip/configs/{name}.json",
+                         "reduced": [], "why": "test"})
+    m["workloads"].append({"name": "tiny.closed2", "config": name,
+                           "traffic": "closed2", "chips": 1, "why": "test"})
+    return root, bench, m
+
+
+def test_a_cell_added_from_files_alone(tmp_path):
+    root, bench, m = checkout_with(tmp_path, {
         "name": "tiny_u8", "image": {"height": 40, "width": 60, "dtype": "uint8",
                                      "content": "smooth_frames"},
         "requests": [{"ops": ["dilate"], "se_sizes": [3]}],
-        "service": {"max_batch": 2}}))
-    (bench / "traffic" / "closed2.json").write_text(json.dumps(
-        {"loop": "closed", "in_flight": 2, "image_pool": 2, "check_sample": 3}))
+        "service": {"max_batch": 2}},
+        {"loop": "closed", "in_flight": 2, "image_pool": 2, "check_sample": 3})
     (bench / "metrics" / "answered.py").write_text(
         "def read(run):\n    return float(len(run.completed_in_window()))\n")
-    m["configs"].append({"name": "tiny_u8", "source": "https://example.org/tiny",
-                         "file": "benchmarks/chip/configs/tiny_u8.json",
-                         "reduced": [], "why": "test"})
-    m["workloads"].append({"name": "tiny.closed2", "config": "tiny_u8",
-                           "traffic": "closed2", "chips": 1, "why": "test"})
     m["end_to_end"].append({"name": "answered", "unit": "images", "better": "higher",
                             "bound": 0.05, "source": "host_clock",
                             "workloads": ["tiny.closed2"]})
@@ -95,6 +108,75 @@ def test_a_cell_added_from_files_alone(tmp_path):
     assert out["correct"] is True
     assert out["metrics"]["answered"]["value"] > 0
     assert set(out["metrics"]) == {"setup_s", "answered"}
+
+
+# Content generators of other pixel types, as a configuration's own file
+# brings them; each reads a key of its own from the configuration's image.
+U16_CONTENT = """import numpy as np
+
+from chipbench.traffic import rng_for
+
+
+def make(image, count, seed):
+    h, w = int(image["height"]), int(image["width"])
+    rng = rng_for(seed, "reflectance")
+    x = rng.integers(0, int(image["reflectance_max"]) + 1, (count, h, w)).astype(np.uint16)
+    x[:, ::7, ::5] = 65535  # saturated
+    x[:, 3::11, ::3] = 0    # no data
+    return x
+"""
+
+BOOL_CONTENT = """import numpy as np
+
+from chipbench.traffic import rng_for
+
+
+def make(image, count, seed):
+    h, w = int(image["height"]), int(image["width"])
+    rng = rng_for(seed, "masks")
+    blocks = rng.random((count, h // 4 + 1, w // 4 + 1)) < float(image["density"])
+    return np.repeat(np.repeat(blocks, 4, 1), 4, 2)[:, :h, :w]
+"""
+
+OTHER_TYPES = {  # case: (configuration, its content, tiled route)
+    "uint16-tiled": ({
+        "name": "tiny_u16", "image": {"height": 100, "width": 140, "dtype": "uint16",
+                                      "content": "tiny_reflectance", "reflectance_max": 10000},
+        "requests": [{"ops": ["opening", "closing"], "se_sizes": [3, 5]}],
+        "service": {"buckets": [[64, 128]], "tile_interior": [32, 64],
+                    "max_tiles_per_launch": 4}}, U16_CONTENT, True),
+    "bool-bucketed": ({
+        "name": "tiny_bool", "image": {"height": 40, "width": 60, "dtype": "bool",
+                                       "content": "tiny_masks", "density": 0.4},
+        "requests": [{"ops": ["erode", "dilate", "gradient"], "se_sizes": [3, 5]}],
+        "service": {"max_batch": 2}}, BOOL_CONTENT, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(OTHER_TYPES))
+def test_a_cell_of_another_pixel_type_added_from_files_alone(tmp_path, case):
+    """A u16 cell on the tiled route and a bool cell on the bucketed route,
+    each added as files alone (configuration, traffic, content): correct when
+    served, and not correct when the control stands in for the answers."""
+    config, content, tiled = OTHER_TYPES[case]
+    root, bench, m = checkout_with(tmp_path, config, {"loop": "closed", "in_flight": 2,
+                                                      "image_pool": 2, "check_sample": 6},
+                                   content)
+    (bench / "metrics" / "tiled_launches.py").write_text(
+        "def read(run):\n    return run.counter_delta('tiled.launches')\n")
+    m["end_to_end"].append({"name": "tiled_launches", "unit": "launches", "better": "lower",
+                            "bound": 0.05, "source": "host_clock",
+                            "workloads": ["tiny.closed2"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(m))
+    spec = harness.cell_spec(harness.load_manifest(str(root)), "tiny.closed2", str(root))
+    harness.import_program(ROOT)
+    for control in (False, True):
+        out = harness.run_cell(spec, 2**35 + 3, 0.6, False, t_start=time.perf_counter(),
+                               require_tpu=False, control=control)
+        assert out["failed"] == 0 and out["checks"]["compared_answers"]["value"] >= 1
+        assert out["correct"] is not control, (control, out["checks"])
+        assert (out["checks"]["wrong_pixels"]["value"] > 0) is control
+        assert (out["metrics"]["tiled_launches"]["value"] > 0) is tiled
 
 
 # ------------------------------------------------------- whole runs on the CPU
@@ -266,7 +348,7 @@ def test_counters_are_the_sum_over_replicas():
     try:
         before = stack.frontier.metrics_snapshot()
         kinds = gen.request_kinds(cfg)
-        imgs = gen.image_pool(cfg, 2, 5)
+        imgs = gen.image_pool(cfg, 2, 5, BENCH)
         futs = [stack.client.submit_plan(imgs[i % 2], harness.server_plan(k))
                 for i, k in enumerate(kinds * 2)]
         futs += [stack.services[1].submit_plan(imgs[0], harness.server_plan(kinds[0]))]
@@ -350,7 +432,7 @@ def test_traced_spans_come_from_every_replica():
     cfg, stack = replica_stack(traced=True)
     try:
         kinds = gen.request_kinds(cfg)
-        img = gen.image_pool(cfg, 1, 3)[0]
+        img = gen.image_pool(cfg, 1, 3, BENCH)[0]
         for f in [stack.client.submit_plan(img, harness.server_plan(k)) for k in kinds]:
             f.result(timeout=120)
         pids = {ev["pid"] for ev in stack.trace_events()
