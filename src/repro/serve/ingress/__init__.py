@@ -45,9 +45,11 @@ from repro.serve.ingress.proto import (
     encode_frame,
     encode_result,
     encode_tensor,
+    frame_buffers,
     plan_from_wire,
     plan_to_wire,
     read_frame,
+    send_frame,
 )
 from repro.serve.ingress.stats import (
     fleet_stats,
@@ -67,7 +69,9 @@ __all__ = [
     "MAGIC",
     "ProtocolError",
     "ConnectionLost",
+    "frame_buffers",
     "encode_frame",
+    "send_frame",
     "read_frame",
     "encode_tensor",
     "decode_tensor",

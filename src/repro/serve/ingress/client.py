@@ -122,11 +122,10 @@ class Connection:
             self._pending[rid] = fut
         return rid, fut
 
-    def _send(self, rid: int, header: dict, payload: bytes = b"") -> None:
-        buf = proto.encode_frame(header, payload)
+    def _send(self, rid: int, header: dict, payload=b"") -> None:
         try:
             with self._wlock:
-                self.sock.sendall(buf)
+                proto.send_frame(self.sock, header, payload)
         except OSError as exc:
             with self._lock:
                 self._pending.pop(rid, None)

@@ -23,7 +23,7 @@ Message types (the frozen-schema tests snapshot these key sets):
 
 * ``submit``    — plan spec + tensor meta (+ payload), ``deadline_ms``,
   ``tag``, ``tenant``, ``priority``, ``trace``;
-* ``result``    — named output tensors, concatenated in the payload;
+* ``result``    — named output tensors, one after another in the payload;
 * ``error``     — a :func:`encode_error` dict; :func:`decode_error`
   reconstructs the *same* typed exception client-side;
 * ``stats`` / ``stats_result`` — a worker's ``metrics_snapshot()`` (the
@@ -35,6 +35,16 @@ Message types (the frozen-schema tests snapshot these key sets):
   span count;
 * ``shutdown`` / ``shutdown_result`` — ask a worker host to drain and
   close (the remote handle on its drain-then-reject shutdown).
+
+Payloads travel as buffers, never as joined or sliced ``bytes``: a frame
+goes out as ``[prefix + header, *payload buffers]`` (:func:`frame_buffers`,
+written by :func:`send_frame` with ``sendmsg``), where each buffer is a
+byte view of a C-contiguous array's own memory (:func:`encode_tensor`
+copies an array only when it is not contiguous); it comes in with its
+payload ``readinto`` one fresh buffer (:func:`read_frame`), and
+:func:`decode_tensor` / :func:`decode_result` return views of that buffer.
+A page crosses a hop with no user-space copy beyond the socket's own.
+Decoded arrays alias their frame's buffer, so buffers are never reused.
 
 Error transport is lossless by construction: :func:`decode_error` rebuilds
 the exception via ``cls.__new__`` + attribute restore instead of calling
@@ -94,22 +104,47 @@ class ConnectionLost(ServeError):
 
 
 # ------------------------------------------------------------------- framing
-def encode_frame(header: dict, payload: bytes = b"") -> bytes:
-    """One wire frame as bytes (write with a single ``sendall`` so frames
-    from concurrent responders never interleave)."""
+def frame_buffers(header: dict, payload=b"") -> list[memoryview]:
+    """One wire frame as buffers: the prefix and header in one, then the
+    payload's buffers as they are, none of them copied. ``payload`` is one
+    bytes-like or a sequence of them (:func:`encode_result` gives one
+    buffer an output)."""
     hdr = json.dumps(header, default=_json_default).encode()
-    return b"".join(
-        (_FRAME.pack(MAGIC, PROTOCOL_VERSION, len(hdr), len(payload)),
-         hdr, payload)
-    )
+    parts = payload if isinstance(payload, (list, tuple)) else (payload,)
+    bufs = [memoryview(p) for p in parts]
+    plen = sum(b.nbytes for b in bufs)
+    head = _FRAME.pack(MAGIC, PROTOCOL_VERSION, len(hdr), plen) + hdr
+    return [memoryview(head), *(b for b in bufs if b.nbytes)]
 
 
-def read_frame(rfile) -> tuple[dict, bytes] | None:
+def encode_frame(header: dict, payload=b"") -> bytes:
+    """One wire frame as bytes: the buffers of :func:`frame_buffers`,
+    joined (for tests and hand-built frames; the served path sends the
+    buffers with :func:`send_frame`)."""
+    return b"".join(frame_buffers(header, payload))
+
+
+def send_frame(sock, header: dict, payload=b"") -> None:
+    """Write one frame to a connected socket with ``sendmsg`` over its
+    buffers, resuming after a partial send. The caller holds the
+    connection's write lock, so frames never interleave."""
+    bufs = frame_buffers(header, payload)
+    while bufs:
+        sent = sock.sendmsg(bufs)
+        while bufs and sent >= bufs[0].nbytes:
+            sent -= bufs.pop(0).nbytes
+        if sent:
+            bufs[0] = bufs[0][sent:]
+
+
+def read_frame(rfile) -> tuple[dict, memoryview] | None:
     """Read one frame from a buffered binary file-like. Returns ``(header,
-    payload)``; ``None`` on clean EOF at a frame boundary. Raises
-    :class:`ProtocolError` for bad magic/version/JSON (the offending frame
-    is consumed first, so the connection survives and can carry the typed
-    error back) and :class:`ConnectionLost` for EOF mid-frame."""
+    payload)``, the payload a ``memoryview`` of a fresh buffer that the
+    frame's bytes were read into; ``None`` on clean EOF at a frame
+    boundary. Raises :class:`ProtocolError` for bad magic/version/JSON (the
+    offending frame is consumed first, so the connection survives and can
+    carry the typed error back) and :class:`ConnectionLost` for EOF
+    mid-frame."""
     prefix = rfile.read(_FRAME.size)
     if not prefix:
         return None  # clean EOF between frames
@@ -121,21 +156,30 @@ def read_frame(rfile) -> tuple[dict, bytes] | None:
         raise ProtocolError(f"bad frame magic {magic!r}")
     if hlen > MAX_HEADER or plen > MAX_PAYLOAD:
         raise ProtocolError(f"frame lengths out of range ({hlen}, {plen})")
-    body = rfile.read(hlen + plen)
-    if len(body) < hlen + plen:
-        raise ConnectionLost("EOF inside a frame body")
+    hdr = rfile.read(hlen)
+    if len(hdr) < hlen:
+        raise ConnectionLost("EOF inside a frame header")
+    # un-zeroed: readinto fills every byte. Decoded arrays alias it and
+    # live on (queues, reroutes, results), so each frame gets its own.
+    payload = memoryview(np.empty(plen, np.uint8))
+    got = 0
+    while got < plen:
+        n = rfile.readinto(payload[got:])
+        if not n:
+            raise ConnectionLost("EOF inside a frame payload")
+        got += n
     if version != PROTOCOL_VERSION:
         raise ProtocolError(
             f"unsupported protocol version {version} "
             f"(this side speaks {PROTOCOL_VERSION})"
         )
     try:
-        header = json.loads(body[:hlen])
+        header = json.loads(hdr)
     except ValueError as exc:
         raise ProtocolError(f"unparseable frame header: {exc}") from None
     if not isinstance(header, dict):
         raise ProtocolError("frame header must be a JSON object")
-    return header, body[hlen:]
+    return header, payload
 
 
 def _json_default(v):
@@ -151,50 +195,84 @@ def _json_default(v):
 
 
 # ------------------------------------------------------------------- tensors
-def encode_tensor(arr: np.ndarray) -> tuple[dict, bytes]:
-    """``(meta, bytes)`` for one array. ``dtype.str`` carries the byte
-    order, so bool (``|b1``) and every multi-byte dtype reconstruct
-    exactly."""
-    arr = np.ascontiguousarray(arr)
-    return {"dtype": arr.dtype.str, "shape": list(arr.shape)}, arr.tobytes()
+def _encode(arr) -> tuple[dict, memoryview, int]:
+    """``(meta, buffer, bytes copied)`` for one array. The buffer is a
+    flat byte view of the array's own memory where it is C-contiguous, else
+    of one contiguous copy. ``dtype.str`` carries the byte order, so bool
+    (``|b1``) and every multi-byte dtype reconstruct exactly."""
+    arr = np.asarray(arr)
+    copied = 0
+    if not arr.flags.c_contiguous:
+        arr = np.ascontiguousarray(arr)
+        copied = arr.nbytes
+    meta = {"dtype": arr.dtype.str, "shape": list(arr.shape)}
+    return meta, memoryview(arr.reshape(-1).view(np.uint8)), copied
+
+
+def encode_tensor(arr: np.ndarray) -> tuple[dict, memoryview]:
+    """``(meta, buffer)`` for one array, the buffer a flat byte view of its
+    memory (of one contiguous copy where it is not C-contiguous)."""
+    meta, buf, _ = _encode(arr)
+    return meta, buf
+
+
+def _tensor_nbytes(meta: dict) -> int:
+    shape = tuple(meta["shape"])
+    return int(np.prod(shape, dtype=np.int64)) * np.dtype(meta["dtype"]).itemsize
 
 
 def decode_tensor(meta: dict, buf) -> np.ndarray:
+    """The array ``meta`` describes, as a view of ``buf``'s bytes."""
     dt = np.dtype(meta["dtype"])
     shape = tuple(meta["shape"])
-    n = int(np.prod(shape, dtype=np.int64)) * dt.itemsize
-    if len(buf) < n:
+    n = _tensor_nbytes(meta)
+    buf = memoryview(buf)
+    if buf.nbytes < n:
         raise ProtocolError(
-            f"tensor payload short: {len(buf)} bytes for {shape} {dt}"
+            f"tensor payload short: {buf.nbytes} bytes for {shape} {dt}"
         )
     return np.frombuffer(buf[:n], dtype=dt).reshape(shape)
 
 
-def encode_result(result) -> tuple[dict, bytes]:
+def encode_result(result, metrics=None) -> tuple[dict, list[memoryview]]:
     """A service result — a bare array (single-output plans) or a
-    ``{name: array}`` dict — as ``(meta, payload)``. The meta records which
-    shape it was so the client-side API mirrors the local one exactly."""
+    ``{name: array}`` dict — as ``(meta, buffers)``, one buffer an output.
+    The meta records which shape it was so the client-side API mirrors the
+    local one exactly. Where ``metrics`` (a registry) is given, the
+    payload's bytes go to ``ingress.payload_bytes_sent`` and those copied
+    to make an output contiguous to ``ingress.payload_bytes_copied``."""
     if isinstance(result, dict):
-        items = [(str(k), np.asarray(v)) for k, v in result.items()]
+        items = [(str(k), v) for k, v in result.items()]
         kind = "dict"
     else:
-        items = [("out", np.asarray(result))]
+        items = [("out", result)]
         kind = "array"
-    outputs, chunks = [], []
+    outputs, bufs, copied = [], [], 0
     for name, arr in items:
-        meta, raw = encode_tensor(arr)
+        meta, buf, n = _encode(arr)
         meta["name"] = name
         outputs.append(meta)
-        chunks.append(raw)
-    return {"kind": kind, "outputs": outputs}, b"".join(chunks)
+        bufs.append(buf)
+        copied += n
+    if metrics is not None:
+        metrics.counter("ingress.payload_bytes_sent").inc(
+            sum(b.nbytes for b in bufs)
+        )
+        metrics.counter("ingress.payload_bytes_copied").inc(copied)
+    return {"kind": kind, "outputs": outputs}, bufs
 
 
-def decode_result(meta: dict, payload: bytes):
+def decode_result(meta: dict, payload):
+    """The result ``meta`` describes, each output a view of ``payload``:
+    one buffer, as :func:`read_frame` gives it, or :func:`encode_result`'s
+    buffers, joined first."""
+    if isinstance(payload, (list, tuple)):
+        payload = b"".join(payload)
+    buf = memoryview(payload)
     out, off = {}, 0
     for m in meta.get("outputs", ()):
-        dt = np.dtype(m["dtype"])
-        n = int(np.prod(tuple(m["shape"]), dtype=np.int64)) * dt.itemsize
-        out[m["name"]] = decode_tensor(m, payload[off:off + n])
+        n = _tensor_nbytes(m)
+        out[m["name"]] = decode_tensor(m, buf[off:off + n])
         off += n
     if meta.get("kind") == "array":
         return next(iter(out.values()))
@@ -318,7 +396,7 @@ def plan_from_wire(spec: dict):
 def submit_message(req_id: int, plan_spec: dict, arr: np.ndarray, *,
                    deadline_ms: float | None = None, tag: str | None = None,
                    tenant: str | None = None, priority: int = 0,
-                   trace: int | None = None) -> tuple[dict, bytes]:
+                   trace: int | None = None) -> tuple[dict, memoryview]:
     meta, payload = encode_tensor(arr)
     return (
         {
@@ -336,8 +414,9 @@ def submit_message(req_id: int, plan_spec: dict, arr: np.ndarray, *,
     )
 
 
-def result_message(req_id: int, result) -> tuple[dict, bytes]:
-    meta, payload = encode_result(result)
+def result_message(req_id: int, result,
+                   metrics=None) -> tuple[dict, list[memoryview]]:
+    meta, payload = encode_result(result, metrics)
     return {"type": "result", "id": req_id, "result": meta}, payload
 
 
@@ -350,7 +429,9 @@ __all__ = [
     "MAGIC",
     "ProtocolError",
     "ConnectionLost",
+    "frame_buffers",
     "encode_frame",
+    "send_frame",
     "read_frame",
     "encode_tensor",
     "decode_tensor",

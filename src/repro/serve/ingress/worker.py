@@ -35,9 +35,16 @@ is an ``ingress`` span in the service's own tracer, carrying the request's
 trace ID from the header: it opens once the frame is decoded and closes
 once the reply bytes are sent, and times two stages, ``recv`` (decoding
 the tensor and plan, then ``submit_plan``, on the connection's reader
-thread) and ``reply`` (encoding the result and ``sendall``, on whichever
-thread resolves the future). A :class:`MorphService`'s ``queue`` span is
+thread) and ``reply`` (on whichever thread resolves the future: the
+result's outputs as byte views of their own memory, copying only an
+output that is not C-contiguous, then ``proto.send_frame``'s ``sendmsg``
+of the frame's buffers). A :class:`MorphService`'s ``queue`` span is
 its child (``Observability.request_submitted`` runs inside ``recv``).
+Over a service with a metrics registry (a :class:`MorphService`), every
+result frame adds its payload bytes to ``ingress.payload_bytes_sent``
+and the bytes copied to make it contiguous to
+``ingress.payload_bytes_copied``; a host over a :class:`Frontier` counts
+nothing, its workers counted the same payloads.
 
 The module is also the subprocess entry point::
 
@@ -86,6 +93,19 @@ def _open_spans(service) -> int:
     return total
 
 
+def _payload_registry(service):
+    """The registry that counts result payloads (``ingress.payload_bytes_*``):
+    the wrapped service's, where it computes results and has one (a
+    :class:`MorphService`'s, so ``Frontier.metrics_snapshot()`` sums them
+    over replicas). None over a :class:`Frontier`, which relays payloads
+    its workers have counted already."""
+    from repro.serve.ingress.frontier import Frontier
+
+    if isinstance(service, Frontier):
+        return None
+    return getattr(service, "metrics", None)
+
+
 class WorkerHost:
     """Serve one service-like object over the ingress protocol.
 
@@ -104,6 +124,7 @@ class WorkerHost:
         self.worker_id = worker_id
         # the wrapped service's observability runtime (None when off)
         self._obs = getattr(self.service, "_obs", None)
+        self._payload_metrics = _payload_registry(self.service)
         self._lock = threading.Lock()
         self._drained = threading.Condition(self._lock)
         self._closing = False
@@ -140,11 +161,10 @@ class WorkerHost:
         rfile = conn.makefile("rb")
         wlock = threading.Lock()
 
-        def send(header: dict, payload: bytes = b"") -> None:
-            buf = proto.encode_frame(header, payload)
+        def send(header: dict, payload=b"") -> None:
             try:
                 with wlock:
-                    conn.sendall(buf)
+                    proto.send_frame(conn, header, payload)
             except OSError:
                 pass  # client went away; its futures died with it
 
@@ -168,7 +188,7 @@ class WorkerHost:
                 self._conns.discard(conn)
 
     # -------------------------------------------------------------- messages
-    def _dispatch(self, header: dict, payload: bytes, send) -> None:
+    def _dispatch(self, header: dict, payload, send) -> None:
         mtype = header.get("type")
         rid = header.get("id")
         if mtype == "submit":
@@ -215,7 +235,7 @@ class WorkerHost:
                 rid, proto.ProtocolError(f"unknown message type {mtype!r}")
             )[0])
 
-    def _handle_submit(self, header: dict, payload: bytes, send) -> None:
+    def _handle_submit(self, header: dict, payload, send) -> None:
         rid = header.get("id")
         with self._lock:
             if self._closing:
@@ -272,7 +292,9 @@ class WorkerHost:
         def done(f) -> None:
             exc = f.exception()
             if exc is None:
-                reply(lambda: proto.result_message(rid, f.result()))
+                reply(lambda: proto.result_message(
+                    rid, f.result(), self._payload_metrics
+                ))
             else:
                 reply(lambda: proto.error_message(rid, exc))
 

@@ -226,6 +226,137 @@ def test_tensor_short_payload_is_protocol_error():
         proto.decode_tensor(meta, payload[:-1])
 
 
+# ======================================= proto: buffers, not joined bytes
+def _over_socketpair(header, payload, receive):
+    """Send one frame with ``send_frame`` from one end of a socket pair (in
+    a thread: a page outgrows the socket's buffer) and return
+    ``receive(other end)``. With a timeout the sending socket sends what
+    fits in its buffer, so a page takes partial sends."""
+    a, b = socket.socketpair()
+    a.settimeout(60)
+    b.settimeout(60)
+
+    def send():
+        try:
+            proto.send_frame(a, header, payload)
+        finally:
+            a.shutdown(socket.SHUT_WR)
+
+    with a, b, concurrent.futures.ThreadPoolExecutor(1) as ex:
+        sent = ex.submit(send)
+        got = receive(b)
+        sent.result(60)
+    return got
+
+
+def _read_one_frame(sock):
+    with sock.makefile("rb") as rfile:
+        return proto.read_frame(rfile)
+
+
+def _read_all_bytes(sock) -> bytes:
+    chunks = []
+    while chunk := sock.recv(1 << 20):
+        chunks.append(chunk)
+    return b"".join(chunks)
+
+
+def _small(dtype):
+    if dtype is np.bool_:
+        return RNG.integers(0, 2, (13, 17)).astype(np.bool_)
+    info = np.iinfo(dtype)
+    return RNG.integers(info.min, info.max, (13, 17), endpoint=True,
+                        dtype=dtype)
+
+
+@pytest.mark.parametrize("case", [
+    "page", "u8", "u16", "i8", "i16", "bool",
+])
+def test_result_round_trip_over_socketpair(case):
+    if case == "page":  # a 2480x3508 page's two outputs
+        result = {"clean": rand(3508, 2480), "edges": rand(3508, 2480)}
+    else:
+        result = _small({"u8": np.uint8, "u16": np.uint16, "i8": np.int8,
+                         "i16": np.int16, "bool": np.bool_}[case])
+    header, payload = proto.result_message(3, result)
+    got_header, got_payload = _over_socketpair(header, payload,
+                                               _read_one_frame)
+    assert got_header["id"] == 3
+    out = proto.decode_result(got_header["result"], got_payload)
+    want = result if isinstance(result, dict) else {"out": result}
+    got = out if isinstance(out, dict) else {"out": out}
+    assert set(got) == set(want)
+    for k in want:
+        assert got[k].dtype == want[k].dtype
+        np.testing.assert_array_equal(got[k], want[k])
+
+
+@pytest.mark.parametrize("message", ["control", "submit", "result"])
+def test_wire_bytes_equal_encode_frame(message):
+    """The buffers on the wire are byte for byte what ``encode_frame``
+    joins: the protocol (version 1) is unchanged."""
+    img = rand(37, 41)
+    if message == "control":
+        header, payload = {"type": "health", "id": 5, "t": 1.5}, b""
+    elif message == "submit":
+        header, payload = proto.submit_message(
+            9, proto.plan_to_wire(ERODE3), img, tenant="free")
+    else:  # two outputs, one of them a crop view that must be copied
+        header, payload = proto.result_message(
+            9, {"clean": img, "edges": img[3:30, 5:40].astype(np.uint16)[:, ::2]})
+    assert proto.PROTOCOL_VERSION == 1
+    wire = _over_socketpair(header, payload, _read_all_bytes)
+    assert wire == proto.encode_frame(header, payload)
+
+
+def test_send_buffers_are_the_arrays_own_memory():
+    """A C-contiguous array goes out from its own memory; only an array
+    that is not contiguous is copied, once."""
+    img, clean = rand(40, 50), rand(40, 50)
+    crop = rand(64, 64)[:40, :50]
+    header, payload = proto.submit_message(1, {"name": "x"}, img)
+    bufs = proto.frame_buffers(header, payload)
+    assert len(bufs) == 2 and np.shares_memory(np.asarray(bufs[1]), img)
+    header, payload = proto.result_message(1, {"clean": clean, "crop": crop})
+    head, b_clean, b_crop = proto.frame_buffers(header, payload)
+    assert np.shares_memory(np.asarray(b_clean), clean)
+    assert not np.shares_memory(np.asarray(b_crop), crop)
+    assert bytes(b_crop) == crop.tobytes()
+
+
+def test_consecutive_frames_decode_to_separate_memory():
+    import io
+
+    first, second = rand(), rand()
+    stream = io.BytesIO(
+        proto.encode_frame(*proto.result_message(1, first))
+        + proto.encode_frame(*proto.result_message(2, second)))
+    frames = [proto.read_frame(stream) for _ in range(2)]
+    got = [proto.decode_result(h["result"], p) for h, p in frames]
+    assert not np.shares_memory(got[0], got[1])
+    np.testing.assert_array_equal(got[0], first)
+    np.testing.assert_array_equal(got[1], second)
+
+
+@pytest.mark.parametrize("transport", ["bytesio", "socket"])
+def test_eof_inside_payload_readinto_is_connection_lost(transport):
+    import io
+
+    buf = proto.encode_frame(*proto.submit_message(1, {"name": "x"}, rand()))
+    cut = buf[:len(buf) - 100]  # the header whole, the payload short
+    if transport == "bytesio":
+        with pytest.raises(proto.ConnectionLost, match="payload"):
+            proto.read_frame(io.BytesIO(cut))
+        return
+    a, b = socket.socketpair()
+    with a, b:
+        a.sendall(cut)
+        a.shutdown(socket.SHUT_WR)
+        with b.makefile("rb") as rfile, \
+                pytest.raises(proto.ConnectionLost, match="payload"):
+            proto.read_frame(rfile)
+
+
 # ================================================= proto: typed error family
 def _build_error(name, message, ctx, extra):
     cls = proto.WIRE_ERRORS[name]
@@ -700,6 +831,57 @@ def test_frontier_serve_composes_recursively():
     finally:
         for h in hosts:
             h.close()
+
+
+# ============================================ ingress: payload counters
+def _payload_counters(snapshot) -> tuple[int, int]:
+    return tuple(
+        (snapshot.get(f"ingress.payload_bytes_{k}") or {}).get("value", 0)
+        for k in ("sent", "copied")
+    )
+
+
+@pytest.mark.parametrize("route", ["tiled", "bucketed"])
+def test_payload_counters_count_result_bytes_and_copies(route):
+    """A tiled page's outputs are stitched contiguous, so they go out from
+    their own memory: bytes sent, none copied. A bucketed result is a crop
+    of its batch, copied once to go out: its bytes count in both."""
+    svc = MorphService(svc_cfg(tile_interior=(32, 32), max_tiles_per_launch=4))
+    img = rand(100, 90) if route == "tiled" else rand()  # 12 tiles, or (64, 64)
+    with WorkerHost(svc) as host, IngressClient(host.address) as client:
+        out = client.run_plan(img, ERODE3)
+        assert svc.stats()["tiled_requests"] == (route == "tiled")
+        sent, copied = _payload_counters(svc.metrics_snapshot())
+    assert sent == out.nbytes == img.nbytes
+    assert copied == (0 if route == "tiled" else img.nbytes)
+
+
+def test_frontier_snapshot_sums_payload_counters_over_replicas():
+    """Both counters reach ``Frontier.metrics_snapshot()`` summed over the
+    replicas; the edge host over the frontier counts nothing of its own."""
+    hosts = two_hosts()
+    imgs = [rand(40 + i, 50) for i in range(3)]
+    try:
+        with Frontier([h.address for h in hosts],
+                      buckets=((64, 64),)) as front:
+            edge = front.serve()
+            try:
+                with IngressClient(edge.address) as client:
+                    for plan in (ERODE3, DILATE3):
+                        client.run_batch(imgs, plan)
+                per_replica = [_payload_counters(h.service.metrics_snapshot())
+                               for h in hosts]
+                total = _payload_counters(front.metrics_snapshot())
+            finally:
+                edge.close()
+    finally:
+        for h in hosts:
+            h.close()
+    assert owner(ERODE3, 2) != owner(DILATE3, 2)  # both replicas serve
+    assert all(sent > 0 for sent, _ in per_replica)
+    nbytes = 2 * sum(im.nbytes for im in imgs)
+    assert total == (nbytes, nbytes)  # every crop copied, once
+    assert total == tuple(map(sum, zip(*per_replica)))
 
 
 # ===================================================== subprocess fleet (CI)
